@@ -108,6 +108,7 @@ def main() -> None:
                         f" --xla_force_host_platform_device_count={DEVICES}"
                         ).strip()
     env["DP_SCALING_WORKER"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"    # emulated devices; never the chip
     env["PYTHONPATH"] = os.pathsep.join(
         [p for p in [str(__file__).rsplit("/", 2)[0] + "/src",
                      env.get("PYTHONPATH", "")] if p])

@@ -127,6 +127,7 @@ def serve_layouts() -> None:
                         f" --xla_force_host_platform_device_count"
                         f"={LAYOUT_DEVICES}").strip()
     env["SERVE_LAYOUTS_WORKER"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"    # emulated devices; never the chip
     env["PYTHONPATH"] = os.pathsep.join(
         [p for p in [str(pathlib.Path(__file__).resolve().parents[1] / "src"),
                      str(pathlib.Path(__file__).resolve().parents[1]),

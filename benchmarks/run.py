@@ -1,5 +1,9 @@
 """Benchmark orchestrator: one module per paper table/figure + kernel and
-roofline reports.  ``PYTHONPATH=src python -m benchmarks.run``"""
+roofline reports.  ``PYTHONPATH=src python -m benchmarks.run``
+
+Every module runs in this one process; the ones that emulate devices
+re-exec a child with ``JAX_PLATFORMS=cpu``, so a child never reaches for
+a chip this process already holds."""
 from __future__ import annotations
 
 import sys

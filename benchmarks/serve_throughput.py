@@ -195,6 +195,7 @@ def _replica_rows() -> list:
                         " --xla_force_host_platform_device_count="
                         f"{REPLICA_DEVICES}").strip()
     env["SERVE_REPLICA_WORKER"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"    # emulated devices; never the chip
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in [str(__file__).rsplit("/", 2)[0] + "/src",
                     env.get("PYTHONPATH", "")] if p)

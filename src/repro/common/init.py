@@ -14,6 +14,16 @@ def lecun_normal(key, shape, dtype=jnp.float32, in_axis: int = -2):
     return std * jax.random.normal(key, shape, dtype)
 
 
+def conv_lecun_normal(key, shape, dtype=jnp.float32):
+    """LeCun normal for an HWIO conv kernel: every input tap of the
+    receptive field counts, fan_in = kh * kw * c_in.  (Scaling by c_in
+    alone grows activations about 3x per 3x3 layer: at four layers on
+    224x224 inputs the FiLM'd features reach 1e9 and the Simple CNAPs
+    covariance loses all precision.)"""
+    std = 1.0 / np.sqrt(np.prod(shape[:-1]))
+    return std * jax.random.normal(key, shape, dtype)
+
+
 def normal_init(key, shape, std=0.02, dtype=jnp.float32):
     return std * jax.random.normal(key, shape, dtype)
 

@@ -216,8 +216,9 @@ def make_batched_meta_train_step(learner: MetaLearner, lite: LiteSpec,
       (``grad_reduce='pmean'``) or int8 error-feedback compressed
       (``'compressed'``, ``repro.optim.compress.compressed_psum``; the
       per-host residual lives in ``opt_state['ef']``, see
-      :func:`init_ef_state`).  At ``dcn`` size 1 the extra reduction is a
-      singleton all-reduce, so results are bit-identical to the 1-D path.
+      :func:`init_ef_state`).  At ``dcn`` size 1 the pmean path emits no
+      dcn reduction at all, so the program and its results are
+      bit-identical to the 1-D path.
 
     ``accum_steps > 1`` scans that many sequential task chunks per shard
     before the single cross-mesh reduction (gradient accumulation), so
@@ -276,6 +277,7 @@ def make_batched_meta_train_step(learner: MetaLearner, lite: LiteSpec,
             f"{dcn_axis!r} axis")
     shards = dp * dcn
     compressed = grad_reduce == "compressed"
+    reduce_dcn = two_level and (dcn > 1 or compressed)
 
     if mesh is None:
         def step(params: PyTree, opt_state: Dict, batch: TaskBatch, key
@@ -313,7 +315,10 @@ def make_batched_meta_train_step(learner: MetaLearner, lite: LiteSpec,
         # (int8-quantized NaN can decode to finite garbage); pmin over dcn
         # replicates the decision so every host skips or applies together.
         ok = _tree_all_finite(grads) if skip_nonfinite else None
-        if two_level:
+        # a size-1 dcn axis reduces nothing; emitting its singleton
+        # all-reduces anyway changes XLA's fusion of the norm/update around
+        # them and costs the bit identity with the 1-D path
+        if reduce_dcn:
             loss = jax.lax.pmean(loss, dcn_axis)
             acc = jax.lax.pmean(acc, dcn_axis)
             if ok is not None:
@@ -344,7 +349,7 @@ def make_batched_meta_train_step(learner: MetaLearner, lite: LiteSpec,
         key_data = jax.random.key_data(key)
         sharded = functools.partial(
             shard_map, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=tuple(out_specs), check_rep=False)(sharded_body)
+            out_specs=tuple(out_specs), check_vma=False)(sharded_body)
         if compressed:
             if "ef" not in opt_state:
                 raise ValueError("grad_reduce='compressed' needs "

@@ -68,6 +68,7 @@ from repro.core.lite import (LiteSpec, lite_class_stats, lite_segment_sum,
                              subsampled_task_sum)
 from repro.kernels import dispatch
 from repro.kernels.dispatch import mahalanobis_head
+from repro.kernels.tpu_compat import F32_CONTRACT
 from repro.core.set_encoder import (SetEncoderConfig, encode_set,
                                     init_set_encoder)
 from repro.models.backbone import BackboneDef
@@ -276,7 +277,7 @@ def _make_cnaps_family(cfg: MetaLearnerConfig, bb: BackboneDef,
             def encode(p, x):
                 feat = _features(p, x)
                 if simple:
-                    outer = jnp.einsum("bi,bj->bij", feat, feat)
+                    outer = feat[:, :, None] * feat[:, None, :]
                     return dict(feat=feat, outer=outer)
                 return dict(feat=feat)
 
@@ -302,7 +303,7 @@ def _make_cnaps_family(cfg: MetaLearnerConfig, bb: BackboneDef,
             # Simple CNAPs Mahalanobis statistics (paper Eq. in §3.1):
             # Sigma_c = l_c * S_c + (1 - l_c) * S_task + eps*I, l_c = k/(k+1)
             ex2 = sums["outer"] / k_c[:, None, None]
-            cov_c = ex2 - jnp.einsum("ci,cj->cij", mu, mu)
+            cov_c = ex2 - mu[:, :, None] * mu[:, None, :]
             n_tot = jnp.maximum(jnp.sum(counts), 1.0)
             mu_t = jnp.sum(sums["feat"], 0) / n_tot
             ex2_t = jnp.sum(sums["outer"], 0) / n_tot
@@ -397,7 +398,9 @@ def _sub_seg(encode_fn, params, xs, ys, num_classes, key, spec, mask=None):
     enc = encode_fn(params, xs_h)
     sums = jax.tree.map(
         lambda e: scale * jnp.einsum("b...,bc->c...",
-                                     e.astype(jnp.float32), onehot_h), enc)
+                                     e.astype(jnp.float32), onehot_h,
+                                     precision=F32_CONTRACT),
+        enc)
     counts = jnp.sum(jax.nn.one_hot(ys, num_classes, dtype=jnp.float32)
                      * w[:, None], axis=0)
     return sums, counts

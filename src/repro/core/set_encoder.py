@@ -20,7 +20,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from repro.common.init import lecun_normal
+from repro.common.init import conv_lecun_normal, lecun_normal
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,7 +40,7 @@ def init_set_encoder(key: jax.Array, cfg: SetEncoderConfig) -> Dict:
         keys = jax.random.split(key, cfg.conv_blocks + 1)
         for i in range(cfg.conv_blocks):
             params["blocks"].append(
-                dict(w=lecun_normal(keys[i], (3, 3, ch, cfg.conv_width), in_axis=2),
+                dict(w=conv_lecun_normal(keys[i], (3, 3, ch, cfg.conv_width)),
                      b=jnp.zeros((cfg.conv_width,)))
             )
             ch = cfg.conv_width

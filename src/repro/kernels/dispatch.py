@@ -59,6 +59,7 @@ import jax.numpy as jnp
 from repro.kernels import int8_matmul as _im
 from repro.kernels import mahalanobis as _md
 from repro.kernels import segment_pool as _sp
+from repro.kernels.tpu_compat import F32_CONTRACT
 from repro.kernels.tpu_compat import interpret_mode as _interpret
 from repro.optim import quant as _quant
 
@@ -123,8 +124,10 @@ def _segment_sum_expand(e: jnp.ndarray, weights: jnp.ndarray,
     reduce axis 0.  Weights are 0/1 (mask-folded one-hots), so any
     association of the elementwise products is exact — this formula is
     shared by ``naive`` and ``ref`` (no big intermediate to kill: the hop
-    is (B, C, ...) with C = way)."""
-    expanded = jnp.einsum("b...,bc->bc...", e, weights.astype(e.dtype))
+    is (B, C, ...) with C = way).  The expansion is an elementwise
+    product, not a dot, so no matmul precision applies to it."""
+    w = weights.astype(e.dtype).reshape(weights.shape + (1,) * (e.ndim - 1))
+    expanded = e[:, None] * w
     return jnp.sum(expanded, axis=0, dtype=accum_dtype)
 
 
@@ -182,7 +185,7 @@ def _second_moment_naive(f, weights, accum_dtype):
     """Pre-dispatch composite: per-example outer products (B, F, F),
     expanded to (B, C, F, F), reduced over b.  The memory bottleneck —
     kept verbatim as the bit-exact oracle."""
-    outer = jnp.einsum("bi,bj->bij", f, f)
+    outer = f[:, :, None] * f[:, None, :]
     return _segment_sum_expand(outer, weights, accum_dtype)
 
 
@@ -192,8 +195,8 @@ def _second_moment_ref(f, weights, accum_dtype):
     then contract the example axis on the MXU/GEMM.  Same math as naive;
     dot-vs-reduce accumulation orders differ, so bits may differ at the
     last ulp (fp32 ~1e-5 at N=1000)."""
-    t = jnp.einsum("bc,bi->bci", weights.astype(f.dtype), f)
-    return jnp.einsum("bci,bj->cij", t, f,
+    t = weights.astype(f.dtype)[:, :, None] * f[:, None, :]
+    return jnp.einsum("bci,bj->cij", t, f, precision=F32_CONTRACT,
                       preferred_element_type=accum_dtype or f.dtype
                       ).astype(accum_dtype or f.dtype)
 

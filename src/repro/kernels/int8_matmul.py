@@ -6,8 +6,10 @@ the weight-stationary serving path.
 ``q``/``scale`` are the blockwise int8 form of ``repro.optim.quant``
 (absmax per 128-wide block of the trailing dim), so the weight matrix is
 never materialized in f32: each grid step loads an int8 (bk, 128) weight
-tile plus its (bk, 1) scale column, applies the scale in-register, and
-feeds the MXU with fp32 accumulation over the K grid axis.  The N tile is
+tile plus the full-width (bk, N/128) scale tile (a (bk, 1) column block
+breaks Mosaic's (8, 128) block tiling whenever N > 128), selects its scale
+column in-register, applies it, and feeds the MXU with fp32 accumulation
+over the K grid axis.  The N tile is
 pinned to ``BLOCK`` so one grid cell always covers exactly one scale
 block — the per-block scale application the quantization scheme implies,
 with no cross-block gather.
@@ -34,6 +36,7 @@ def _round_up(v: int, m: int) -> int:
 
 
 def _int8_matmul_kernel(x_ref, q_ref, s_ref, o_ref):
+    ni = pl.program_id(1)
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -41,8 +44,11 @@ def _int8_matmul_kernel(x_ref, q_ref, s_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...].astype(jnp.float32)                    # (bm, bk)
+    s_all = s_ref[...]                                    # (bk, N/BLOCK)
+    col = jax.lax.broadcasted_iota(jnp.int32, s_all.shape, 1)
+    s = jnp.sum(jnp.where(col == ni, s_all, 0.0), axis=1, keepdims=True)
     # per-block scale application: (bk, BLOCK) int8 tile * (bk, 1) scales
-    w = q_ref[...].astype(jnp.float32) * s_ref[...]
+    w = q_ref[...].astype(jnp.float32) * s
     o_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -67,7 +73,7 @@ def int8_matmul(x: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray, *,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
             pl.BlockSpec((bk, BLOCK), lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((bk, 1), lambda mi, ni, ki: (ki, ni)),
+            pl.BlockSpec((bk, np_ // BLOCK), lambda mi, ni, ki: (ki, 0)),
         ],
         out_specs=pl.BlockSpec((bm, BLOCK), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),

@@ -18,7 +18,9 @@ labels-based entry point on top of it.
 
 computed per (class, F_i-tile, F_j-tile) grid cell as one MXU matmul
 ((F_i, B_t) x (B_t, F_j), the class weight folded into the left operand)
-accumulated over the B grid axis — the per-example (B, F, F)
+accumulated over the B grid axis.  Each cell loads the full-width (B_t, C)
+weight tile (a (B_t, 1) column slice breaks Mosaic's (8, 128) block
+tiling) and selects its class column in-register.  The per-example (B, F, F)
 outer-product tensor is never formed, which is the whole point
 (repro.kernels.dispatch routes the episodic hot path here).
 """
@@ -29,9 +31,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
+from repro.kernels.tpu_compat import F32_CONTRACT, CompilerParams
 
 
 def _pool_kernel(w_ref, x_ref, o_ref, *, block_b: int, n_rows: int):
@@ -49,7 +50,8 @@ def _pool_kernel(w_ref, x_ref, o_ref, *, block_b: int, n_rows: int):
     w = jnp.where(valid, w, 0.0)
     x = jnp.where(valid, x, 0.0)
     o_ref[...] += jax.lax.dot_general(
-        w, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        w, x, (((0,), (0,)), ((), ())), precision=F32_CONTRACT,
+        preferred_element_type=jnp.float32)
 
 
 def segment_pool_weighted(x: jnp.ndarray, weights: jnp.ndarray, *,
@@ -94,7 +96,11 @@ def _second_moment_kernel(w_ref, xi_ref, xj_ref, o_ref, *, block_b: int,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    w = w_ref[...].astype(jnp.float32)                # (bb, 1) — class ci
+    ci = pl.program_id(0)
+    w_all = w_ref[...].astype(jnp.float32)            # (bb, C)
+    col = jax.lax.broadcasted_iota(jnp.int32, w_all.shape, 1)
+    w = jnp.sum(jnp.where(col == ci, w_all, 0.0), axis=1,
+                keepdims=True)                        # (bb, 1) — class ci
     xi = xi_ref[...].astype(jnp.float32)              # (bb, Ft_i)
     xj = xj_ref[...].astype(jnp.float32)              # (bb, Ft_j)
     valid = (bi * block_b +
@@ -105,7 +111,7 @@ def _second_moment_kernel(w_ref, xi_ref, xj_ref, o_ref, *, block_b: int,
     # (Ft_i, bb) x (bb, Ft_j) with the class weight folded into the left
     # operand: sum_b w[b] xi[b, i] xj[b, j]
     o_ref[0] += jax.lax.dot_general(
-        xi * w, xj, (((0,), (0,)), ((), ())),
+        xi * w, xj, (((0,), (0,)), ((), ())), precision=F32_CONTRACT,
         preferred_element_type=jnp.float32)
 
 
@@ -123,7 +129,7 @@ def class_second_moment(x: jnp.ndarray, weights: jnp.ndarray, *,
         grid=(c, pl.cdiv(f, block_f), pl.cdiv(f, block_f),
               pl.cdiv(b, block_b)),
         in_specs=[
-            pl.BlockSpec((block_b, 1), lambda ci, fi, fj, bi: (bi, ci)),
+            pl.BlockSpec((block_b, c), lambda ci, fi, fj, bi: (bi, 0)),
             pl.BlockSpec((block_b, block_f), lambda ci, fi, fj, bi: (bi, fi)),
             pl.BlockSpec((block_b, block_f), lambda ci, fi, fj, bi: (bi, fj)),
         ],

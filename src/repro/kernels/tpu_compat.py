@@ -1,16 +1,26 @@
-"""Version tolerance for the Pallas TPU compiler-params dataclass, plus
-the one shared backend-detection policy.
+"""The one import site of the Pallas TPU compiler-params dataclass, the
+one shared backend-detection policy, and the one float32 contract
+precision.
 
-jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``; depending
-on the installed jax exactly one of the two names exists.  Kernels import
-``CompilerParams`` from here so they lower on either side of the rename.
+jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``; kernels
+import ``CompilerParams`` from here (``repro.lint``'s ``jax-api-drift`` rule
+enforces it) so the next rename is absorbed in one place.
 """
 from __future__ import annotations
 
 import jax
 import jax.experimental.pallas.tpu as pltpu
 
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+CompilerParams = pltpu.CompilerParams
+
+# Simple CNAPs subtracts nearly equal float32 quantities twice: the class
+# covariance E[x x^T] - mu mu^T, and the Mahalanobis form (q - mu)^T S (q - mu)
+# of large, nearby features.  On TPU the default matmul precision rounds dot
+# operands to bfloat16, and that error then cancels into an indefinite
+# covariance (a NaN Cholesky factor) or a wrong distance.  The contractions
+# that feed those two differences (the class statistics and the Mahalanobis
+# kernel) run at this precision; it changes nothing on CPU.
+F32_CONTRACT = jax.lax.Precision.HIGHEST
 
 
 def interpret_mode() -> bool:

@@ -12,6 +12,7 @@ sweeps resume where they stopped.
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
+os.environ["JAX_PLATFORMS"] = "cpu"  # placeholder devices; never the chip
 os.environ["REPRO_MIXED_DOT"] = "1"   # AOT-only: bf16 dots w/ f32 accum
 
 import argparse

@@ -36,7 +36,12 @@ chooser scores ONE replica group and the winner applies to all:
     PYTHONPATH=src python -m repro.launch.serve --episodic \
         --replicas 2 --serve-layout auto --serve-quant int8 --requests 16
 
-Runs the smoke config on this container; on a TPU slice the same engines
+The episodic backbone and task shape take the flags of
+``repro.launch.episodic`` (toy defaults; ``--widths 32,64,128,256
+--feature-dim 256 --image-size 224 ...`` is the paper's regime).  A run in
+which any request is rejected, abandoned or failed exits non-zero.
+
+The LM path runs the smoke config; on a TPU slice the same engines
 serve the full config (params sharded by repro.sharding.rules — see
 EXPERIMENTS.md §Perf cell 2 for the topology guidance: size the slice so
 weights are resident, don't decode one stream set on a full pod).
@@ -44,12 +49,14 @@ weights are resident, don't decode one stream set on a full pod).
 from __future__ import annotations
 
 import argparse
+import sys
 import time
-from typing import Callable
+from typing import Callable, Optional, Sequence
 
 import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCH_IDS, get_smoke_config
 from repro.models.registry import get_api
 from repro.serve.engine import Request, ServeEngine
@@ -62,22 +69,14 @@ def run_episodic(args, clock: Callable[[], float] = time.monotonic) -> None:
     deterministic.  The engine itself receives its own injectable clock
     via ``EpisodicServeEngine(clock=...)``."""
     from repro.core.lite import LiteSpec
-    from repro.core.meta_learners import MetaLearnerConfig, make_learner
-    from repro.core.set_encoder import SetEncoderConfig
-    from repro.data.episodic import (EpisodicImageConfig, plan_buckets,
-                                     sample_image_task)
-    from repro.models.conv_backbone import (ConvBackboneConfig,
-                                            make_conv_backbone)
+    from repro.data.episodic import plan_buckets, sample_image_task
+    from repro.launch.episodic import build_learner, shape_from_args
     from repro.serve.episodic import EpisodicRequest, EpisodicServeEngine
 
-    backbone = make_conv_backbone(ConvBackboneConfig(widths=(16, 32),
-                                                     feature_dim=64))
-    learner = make_learner(
-        MetaLearnerConfig(kind=args.learner, way=5), backbone,
-        SetEncoderConfig(kind="conv", conv_blocks=2, conv_width=16,
-                         task_dim=32))
+    shape = shape_from_args(args)
+    learner = build_learner(args.learner, shape)
     params = learner.init(jax.random.key(0))
-    lite = LiteSpec(exact=True, chunk_size=args.lite_chunk,
+    lite = LiteSpec(exact=True, chunk_size=shape.lite_chunk,
                     compute_dtype=args.lite_dtype)
 
     # synthetic personalization traffic: exactly n_users distinct users
@@ -87,13 +86,13 @@ def run_episodic(args, clock: Callable[[], float] = time.monotonic) -> None:
     rng = np.random.default_rng(0)
     n_users = max(1, round(args.requests * (1.0 - args.repeat_frac)))
     n_users = min(n_users, args.requests)
-    cfg = EpisodicImageConfig(way=5, shot=args.shot, query_per_class=4,
-                              image_size=args.image_size)
+    cfg = shape.image_config()
+
     def request_for(uid):
         t = sample_image_task(jax.random.key(uid), cfg)
         return EpisodicRequest(uid=uid, support_x=np.asarray(t.support_x),
                                support_y=np.asarray(t.support_y),
-                               query_x=np.asarray(t.query_x))
+                               query_x=np.asarray(t.query_x), way=shape.way)
 
     cold = [request_for(uid) for uid in range(n_users)]
     warm = [request_for(int(rng.integers(0, n_users)))
@@ -172,9 +171,8 @@ def run_episodic(args, clock: Callable[[], float] = time.monotonic) -> None:
     engine.run_to_completion(warm)
     dt = clock() - t0
     s = engine.stats()
-    # every request reaches a terminal outcome: served, or a counted
-    # degradation (backpressure rejection / deadline abandonment / failed)
-    assert all(r.done or r.rejected for r in reqs)
+    unserved = [r.uid for r in reqs
+                if not r.done or r.rejected or r.abandoned or r.failed]
     print(f"episodic serve: learner={args.learner} {len(reqs)} requests "
           f"({n_users} distinct users) in {dt:.2f}s on {args.slots} slots")
     print(f"  tasks adapted {s['tasks_adapted']} "
@@ -216,9 +214,17 @@ def run_episodic(args, clock: Callable[[], float] = time.monotonic) -> None:
     for r in reqs[:4]:
         print(f"  req uid={r.uid}: cache_hit={r.cache_hit} "
               f"preds={r.predictions()[:8].tolist()}")
+    if unserved:
+        # rejected, abandoned and failed requests are counted degradations,
+        # not successes: the run must not exit 0 over them
+        sys.exit(f"{len(unserved)} of {len(reqs)} requests were not served "
+                 f"(uids {unserved[:8]})")
 
 
-def main(clock: Callable[[], float] = time.monotonic) -> None:
+def main(clock: Callable[[], float] = time.monotonic,
+         argv: Optional[Sequence[str]] = None) -> None:
+    from repro.launch.episodic import EpisodicShape, add_shape_args
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="minitron-4b")
     ap.add_argument("--requests", type=int, default=8)
@@ -231,14 +237,12 @@ def main(clock: Callable[[], float] = time.monotonic) -> None:
     ap.add_argument("--learner", default="protonets",
                     choices=["protonets", "cnaps", "simple_cnaps", "fomaml",
                              "finetuner"])
-    ap.add_argument("--shot", type=int, default=10)
-    ap.add_argument("--image-size", type=int, default=24)
+    add_shape_args(ap, EpisodicShape(query_per_class=4, lite_chunk=32),
+                   lite_h=False)
     ap.add_argument("--query-chunk", type=int, default=8)
     ap.add_argument("--repeat-frac", type=float, default=0.5,
                     help="fraction of requests from repeat users "
                          "(task-state cache hits)")
-    ap.add_argument("--lite-chunk", type=int, default=32,
-                    help="LITE serve-time adaptation chunk size")
     ap.add_argument("--cache-capacity", type=int, default=64,
                     help="L1 task-state LRU capacity (resident adapted "
                          "states); evictions spill to --warm-dir when set")
@@ -315,7 +319,8 @@ def main(clock: Callable[[], float] = time.monotonic) -> None:
                          "kernels (interpret off-TPU), auto = pallas on "
                          "TPU else ref, naive = materializing legacy "
                          "composite")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.episodic:
         run_episodic(args, clock=clock)

@@ -37,17 +37,27 @@ Mahalanobis aggregation kernels):
     PYTHONPATH=src python -m repro.launch.train --episodic \
         --steps 100 --tasks-per-step 8 --dp-shards 1 \
         --data-source host --prefetch 4 --schedule cosine
+
+The backbone and task shape (``--widths``, ``--feature-dim``, ``--way``,
+``--shot``, ``--query-per-class``, ``--image-size``, ``--lite-h``,
+``--lite-chunk``) default to a toy size; ``repro.launch.episodic`` gives
+the flags for the paper's full-width 224x224 regime.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
 import sys
+import tempfile
+from typing import Callable, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
 from repro.configs.base import SHAPES_BY_NAME, MetaTrainConfig
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.data.tokens import TokenPipeline, TokenPipelineConfig
 from repro.launch.mesh import (make_dp_mesh, make_production_mesh,
@@ -57,7 +67,7 @@ from repro.sharding import rules
 from repro.sharding.ctx import P
 from repro.faults import PreemptionSignal
 from repro.train.checkpoint import CheckpointManager
-from repro.train.loop import PreemptedError, train
+from repro.train.loop import PreemptedError, TrainResult, train
 from repro.train.step import (adamw_for, make_episodic_init_state,
                               make_episodic_train_step, make_init_state,
                               make_train_step)
@@ -79,16 +89,27 @@ def _fault_summary(result) -> str:
             f"stragglers={result.straggler_steps}")
 
 
-def run_episodic(args) -> None:
+@dataclasses.dataclass
+class EpisodicJob:
+    """Everything ``run_episodic`` hands the fault-tolerant loop, built
+    from the launcher's arguments (``chip_smoke.py`` drives the same)."""
+
+    meta: MetaTrainConfig
+    state: Dict
+    state_abs: Dict
+    step: Callable
+    batch_at: Callable[[int], Dict]
+    batch_put: Optional[Callable]
+    ckpt_dir: str
+
+
+def build_episodic(args) -> EpisodicJob:
     from repro.core.lite import LiteSpec
-    from repro.core.meta_learners import MetaLearnerConfig, make_learner
-    from repro.core.set_encoder import SetEncoderConfig
-    from repro.data.episodic import (EpisodicImageConfig, HostEpisodicConfig,
-                                     host_task_batch_at, task_batch_at)
-    from repro.models.conv_backbone import (ConvBackboneConfig,
-                                            make_conv_backbone)
+    from repro.data.episodic import host_task_batch_at, task_batch_at
+    from repro.launch.episodic import build_learner, shape_from_args
     from repro.optim import AdamWConfig
 
+    shape = shape_from_args(args)
     meta = MetaTrainConfig(tasks_per_step=args.tasks_per_step,
                            dp_shards=args.dp_shards,
                            dcn_shards=args.dcn_shards,
@@ -97,6 +118,7 @@ def run_episodic(args) -> None:
                            schedule=args.schedule,
                            warmup_steps=max(args.steps // 50, 1),
                            total_steps=args.steps,
+                           lite_h=shape.lite_h, lite_chunk=shape.lite_chunk,
                            lite_dtype=args.lite_dtype,
                            prefetch=args.prefetch,
                            donate=not args.no_donate,
@@ -107,7 +129,7 @@ def run_episodic(args) -> None:
         mesh = make_dp_mesh(meta.dp_shards)
     else:
         mesh = None
-    print(f"episodic meta-training: learner={args.learner} "
+    print(f"episodic meta-training: learner={args.learner} {shape} "
           f"tasks_per_step={meta.tasks_per_step} dp_shards={meta.dp_shards} "
           f"dcn_shards={meta.dcn_shards} grad_reduce={meta.grad_reduce} "
           f"accum_steps={meta.accum_steps} "
@@ -117,13 +139,7 @@ def run_episodic(args) -> None:
           f"kernel_backend={meta.kernel_backend} "
           f"devices={len(jax.devices())}")
 
-    backbone = make_conv_backbone(ConvBackboneConfig(widths=(16, 32),
-                                                     feature_dim=64))
-    learner = make_learner(
-        MetaLearnerConfig(kind=args.learner, way=5),
-        backbone,
-        SetEncoderConfig(kind="conv", conv_blocks=2, conv_width=16,
-                         task_dim=32))
+    learner = build_learner(args.learner, shape)
     lite = LiteSpec(h=meta.lite_h, chunk_size=meta.lite_chunk,
                     compute_dtype=meta.lite_dtype)
     adamw = AdamWConfig(weight_decay=0.0)
@@ -151,16 +167,14 @@ def run_episodic(args) -> None:
     if args.data_source == "host":
         # host-side collation+augmentation — the path the prefetcher can
         # genuinely overlap with device compute
-        hcfg = HostEpisodicConfig(way=5, shot=10, query_per_class=6,
-                                  image_size=args.image_size)
+        hcfg = shape.host_config()
 
         def batch_at(s):
             return dict(tasks=host_task_batch_at(17, hcfg,
                                                  meta.tasks_per_step, s),
                         key=jax.random.fold_in(step_key, s))
     else:
-        tcfg = EpisodicImageConfig(way=5, shot=10, query_per_class=6,
-                                   image_size=args.image_size)
+        tcfg = shape.image_config()
         data_key = jax.random.key(17)
 
         def batch_at(s):
@@ -174,16 +188,31 @@ def run_episodic(args) -> None:
     # template is a shape mismatch / missing-leaf KeyError
     suffix = (f"_ef{meta.dcn_shards}"
               if meta.grad_reduce == "compressed" else "")
-    ckpt_dir = args.ckpt_dir or \
-        f"/tmp/repro_train_ckpt_episodic_{args.learner}{suffix}"
-    ckpt = CheckpointManager(ckpt_dir, keep=3)
+    ckpt_dir = args.ckpt_dir or os.path.join(
+        tempfile.gettempdir(),
+        f"repro_train_ckpt_episodic_{args.learner}{suffix}")
+    return EpisodicJob(meta=meta, state=state, state_abs=state_abs,
+                       step=step, batch_at=batch_at, batch_put=batch_put,
+                       ckpt_dir=ckpt_dir)
+
+
+def run_episodic(args, step_hook: Optional[Callable[[int], None]] = None
+                 ) -> Optional[TrainResult]:
+    """Build the job from ``args`` and train it.  ``step_hook(step)`` runs
+    before every step (the loop's ``preemption_hook``).  Exits non-zero
+    when every step of the run was skipped as non-finite."""
+    job = build_episodic(args)
+    meta = job.meta
+    ckpt = CheckpointManager(job.ckpt_dir, keep=3)
     preempt = PreemptionSignal().install()
     try:
-        result = train(state, step, batch_at, args.steps, ckpt=ckpt,
-                       ckpt_every=args.ckpt_every, state_template=state_abs,
+        result = train(job.state, job.step, job.batch_at, args.steps,
+                       ckpt=ckpt, ckpt_every=args.ckpt_every,
+                       state_template=job.state_abs,
                        log_every=max(args.steps // 10, 1),
                        prefetch=meta.prefetch, donate=meta.donate,
-                       batch_put=batch_put, preempt=preempt,
+                       batch_put=job.batch_put, preempt=preempt,
+                       preemption_hook=step_hook,
                        max_nonfinite=args.max_nonfinite_skips,
                        data_retries=args.data_retries)
     except PreemptedError as e:
@@ -191,16 +220,22 @@ def run_episodic(args) -> None:
     if not result.metrics_history:
         print(f"nothing to do: checkpoint already at step {result.step} "
               f"(resumed_from={result.resumed_from})")
-        return
+        return result
     print(f"done at step {result.step}; resumed_from={result.resumed_from}; "
           f"loss {result.metrics_history[0]['loss']:.4f} -> "
           f"{result.metrics_history[-1]['loss']:.4f}; "
           f"accuracy {result.metrics_history[-1]['accuracy']:.3f}; "
           f"throughput {result.throughput(meta.tasks_per_step):.1f} tasks/s; "
           f"{_fault_summary(result)}")
+    if len(result.nonfinite_steps) == len(result.metrics_history):
+        sys.exit(f"every one of the {len(result.metrics_history)} steps was "
+                 f"skipped as non-finite; no update was applied")
+    return result
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    from repro.launch.episodic import EpisodicShape, add_shape_args
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="minitron-4b")
     ap.add_argument("--steps", type=int, default=200)
@@ -211,8 +246,9 @@ def main() -> None:
                          "default constant --peak-lr)")
     ap.add_argument("--peak-lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default=None,
-                    help="defaults to /tmp/repro_train_ckpt (LM) or "
-                         "/tmp/repro_train_ckpt_episodic (--episodic)")
+                    help="defaults to repro_train_ckpt (LM) or "
+                         "repro_train_ckpt_episodic_<learner> (--episodic) "
+                         "under the temporary directory")
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--pods", type=int, default=1)
     ap.add_argument("--full", action="store_true",
@@ -241,7 +277,7 @@ def main() -> None:
                     help="sequential gradient-accumulation chunks per "
                          "optimizer step, so --tasks-per-step can exceed "
                          "per-host memory")
-    ap.add_argument("--image-size", type=int, default=24)
+    add_shape_args(ap, EpisodicShape())
     ap.add_argument("--prefetch", type=int, default=2,
                     help="background batch lookahead depth (0 = sync loop)")
     ap.add_argument("--data-source", choices=["device", "host"],
@@ -273,7 +309,12 @@ def main() -> None:
                          "pallas on TPU else ref, naive = materializing "
                          "legacy composite (bit-exact pre-dispatch "
                          "oracle)")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     if args.episodic:
         run_episodic(args)
@@ -313,8 +354,9 @@ def main() -> None:
         def batch_at(s):
             return {k: jnp.asarray(v) for k, v in pipe.batch_at(s).items()}
 
-        ckpt = CheckpointManager(args.ckpt_dir or "/tmp/repro_train_ckpt",
-                                 keep=3)
+        ckpt = CheckpointManager(
+            args.ckpt_dir or os.path.join(tempfile.gettempdir(),
+                                          "repro_train_ckpt"), keep=3)
         preempt = PreemptionSignal().install()
         try:
             result = train(state, step, batch_at, args.steps,

@@ -37,6 +37,7 @@ def _run_contracts(cells: List[str], as_json: bool) -> int:
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=4").strip()
     env[_WORKER_ENV] = "1"
+    env["JAX_PLATFORMS"] = "cpu"    # emulated devices; never the chip
     env.setdefault("PYTHONPATH", str(engine.repo_root() / "src"))
     cmd = [sys.executable, "-m", "repro.lint", "--contracts", "--no-ast"]
     if as_json:

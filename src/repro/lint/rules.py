@@ -42,9 +42,11 @@ class JaxApiDriftRule(Rule):
     name = "jax-api-drift"
     invariant = ("shard_map and pallas CompilerParams are reached only "
                  "through the repo shims (repro.sharding / "
-                 "repro.kernels.tpu_compat)")
-    recurrence = ("jax moved shard_map out of jax.experimental and renamed "
-                  "TPUCompilerParams; every direct call site broke at once "
+                 "repro.kernels.tpu_compat), and no call passes the "
+                 "removed check_rep= keyword")
+    recurrence = ("jax moved shard_map out of jax.experimental, renamed "
+                  "TPUCompilerParams, and replaced shard_map's check_rep= "
+                  "with check_vma=; every direct call site broke at once "
                   "— the shims absorb the next rename in one place")
 
     _SHIMS = ("src/repro/sharding/__init__.py",
@@ -56,7 +58,12 @@ class JaxApiDriftRule(Rule):
 
     def check(self, ctx: LintContext) -> Iterable[Finding]:
         for node in ast.walk(ctx.tree):
-            if isinstance(node, ast.Attribute):
+            if isinstance(node, ast.keyword) and node.arg == "check_rep":
+                yield ctx.finding(
+                    node.value, self.name,
+                    "check_rep= — jax's shard_map takes check_vma= now "
+                    "and rejects check_rep=")
+            elif isinstance(node, ast.Attribute):
                 dotted = _dotted(node)
                 if dotted in ("jax.shard_map", "jax.experimental.shard_map",
                               "jax.experimental.shard_map.shard_map"):

@@ -16,7 +16,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.common.init import lecun_normal
+from repro.common.init import conv_lecun_normal, lecun_normal
 from repro.core.film import apply_film
 from repro.kernels import dispatch
 from repro.models.backbone import BackboneDef
@@ -36,7 +36,7 @@ def init_conv_backbone(key: jax.Array, cfg: ConvBackboneConfig) -> Dict:
     keys = jax.random.split(key, len(cfg.widths) + 1)
     for i, w in enumerate(cfg.widths):
         params["blocks"].append(
-            dict(w=lecun_normal(keys[i], (3, 3, ch, w), in_axis=2),
+            dict(w=conv_lecun_normal(keys[i], (3, 3, ch, w)),
                  b=jnp.zeros((w,)))
         )
         ch = w

@@ -1,10 +1,9 @@
 """SPMD distribution layer: mesh builders, partition rules, constraints.
 
-Also re-exports ``shard_map`` across the jax relocation (it moved from
-``jax.experimental.shard_map`` to top-level ``jax.shard_map``); all repo
-code and test snippets import it from here — calling ``jax.shard_map``
-directly regresses on older jax (that exact drift broke the optimized MoE
-dispatch variant; see ROADMAP).
+Also re-exports ``shard_map``: all repo code and test snippets import it
+from here, the one import site ``repro.lint``'s ``jax-api-drift`` rule
+enforces, so the next upstream move or keyword rename (``check_rep``
+became ``check_vma``) is absorbed in one place.
 
 Two-level DP mesh contract (the task-batched meta-training engine,
 ``repro.core.episodic_train.make_batched_meta_train_step``):
@@ -24,7 +23,7 @@ Two-level DP mesh contract (the task-batched meta-training engine,
   ``compressed_psum`` (``repro.optim.compress``).  With ``accum_steps``
   the per-shard tasks are scanned in chunks BEFORE the reduction, so the
   collective count per optimizer step never grows.
-* At ``dcn`` size 1 the extra reduction is a singleton all-reduce and the
+* At ``dcn`` size 1 the pmean path skips the dcn reduction, so the
   engine is bit-identical to the 1-D ``make_dp_mesh`` path (tested in
   tests/test_multihost.py).  Per-step collective wire bytes are
   accounted by ``repro.roofline.hlo.collectives_report`` and tracked in
@@ -53,9 +52,6 @@ Replica-serving mesh contract (the multi-replica episodic engine,
   deployment re-routes uids without moving their files.
 """
 
-try:
-    from jax import shard_map  # jax >= 0.6
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["shard_map"]

@@ -34,3 +34,35 @@ def test_serve_launcher():
                 "--requests", "3", "--slots", "2", "--max-new", "5",
                 "--prompt-len", "4"])
     assert "3 requests" in out
+
+
+TINY_SHAPE = ["--widths", "8,16", "--feature-dim", "32", "--way", "4",
+              "--shot", "3", "--query-per-class", "2", "--image-size", "12"]
+
+
+def test_episodic_train_launcher_takes_the_shape(tmp_path):
+    out = _run(["repro.launch.train", "--episodic", "--learner",
+                "simple_cnaps", "--steps", "2", "--tasks-per-step", "2",
+                "--lite-h", "4", "--lite-chunk", "4",
+                "--ckpt-dir", str(tmp_path)] + TINY_SHAPE)
+    assert "widths=(8, 16), feature_dim=32, way=4, shot=3" in out
+    assert "lite_h=4, lite_chunk=4" in out
+    assert "done at step 2" in out
+
+
+def test_episodic_serve_launcher_takes_the_shape():
+    out = _run(["repro.launch.serve", "--episodic", "--learner",
+                "simple_cnaps", "--requests", "4", "--lite-chunk", "4"]
+               + TINY_SHAPE)
+    assert "4 requests" in out and "failed_requests=0" in out
+
+
+def test_episodic_serve_launcher_fails_on_unserved_requests():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--episodic",
+         "--requests", "6", "--max-queue", "1"] + TINY_SHAPE,
+        capture_output=True, text=True, env=env, timeout=480)
+    assert r.returncode != 0
+    assert "requests were not served" in r.stderr

@@ -205,6 +205,19 @@ def test_atomic_publish_ignores_read_and_update_modes():
     assert hits(src, "src/repro/serve/x.py", "atomic-publish") == []
 
 
+@pytest.mark.parametrize("keyword,fires", [("check_rep", True),
+                                            ("check_vma", False)])
+def test_drift_rule_flags_removed_shard_map_keyword(keyword, fires):
+    src = f"""
+        from repro.sharding import shard_map
+        f = shard_map(g, mesh=m, in_specs=s, out_specs=s, {keyword}=False)
+    """
+    found = hits(src, "src/repro/core/x.py", "jax-api-drift")
+    assert bool(found) == fires
+    if fires:
+        assert found[0].line == 3 and "check_vma" in found[0].message
+
+
 def test_drift_rule_skips_the_shims_themselves():
     src = "import jax\nshard_map = jax.shard_map\n"
     assert hits(src, "src/repro/sharding/__init__.py", "jax-api-drift") == []

@@ -1,0 +1,74 @@
+"""The main-path Pallas kernels compile for a TPU v5e chip at real widths.
+
+Nothing runs: each kernel is lowered and compiled for one chip of a
+described (not attached) ``v5e:2x2`` topology, which is what Mosaic
+refuses or accepts — block tiling, VMEM limits — and interpret mode cannot
+show.  Widths are the chip smoke's: B=1000 support images, C=10 classes,
+F=256 features, and a K=N=256 int8 head.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and the test workers all
+import this file.  The persistent compilation cache is off around these
+compiles — an entry written for a described chip cannot be read back.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import int8_matmul as im
+from repro.kernels import mahalanobis as md
+from repro.kernels import segment_pool as sp
+
+B, C, F = 1000, 10, 256
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe it
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile_hlo(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+CASES = {
+    "segment_pool_weighted": (
+        lambda x, w: sp.segment_pool_weighted(x, w),
+        [((B, F), jnp.float32), ((B, C), jnp.float32)]),
+    "class_second_moment": (
+        lambda x, w: sp.class_second_moment(x, w),
+        [((B, F), jnp.float32), ((B, C), jnp.float32)]),
+    "mahalanobis": (
+        lambda q, mu, sinv: md.mahalanobis(q, mu, sinv),
+        [((B, F), jnp.float32), ((C, F), jnp.float32),
+         ((C, F, F), jnp.float32)]),
+    "int8_matmul": (
+        lambda x, q, s: im.int8_matmul(x, q, s),
+        [((B, F), jnp.float32), ((F, F), jnp.int8),
+         ((F, F // 128), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, specs = CASES[name]
+    shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+              for s, dt in specs]
+    assert "tpu_custom_call" in _compile_hlo(fn, *shapes)
