@@ -35,6 +35,10 @@ from repro.optim import AdamWConfig, adamw_update, clip_by_global_norm
 
 PyTree = Any
 
+# names the finite check and the update in the compiled step's op names,
+# which profiler traces carry (``bench/layers.py`` reads them)
+OPTIMIZER_SCOPE = "train.optimizer"
+
 
 def make_meta_train_step(learner: MetaLearner, lite: LiteSpec,
                          query_batch: int = 0,
@@ -145,10 +149,11 @@ def _tree_all_finite(tree: PyTree) -> jnp.ndarray:
     check inside the step's jit — the guard the fault-tolerant loop relies
     on to turn a NaN/inf gradient into a skipped step instead of silent
     parameter corruption."""
-    ok = jnp.asarray(True)
-    for leaf in jax.tree.leaves(tree):
-        ok = jnp.logical_and(ok, jnp.all(jnp.isfinite(leaf)))
-    return ok
+    with jax.named_scope(OPTIMIZER_SCOPE):
+        ok = jnp.asarray(True)
+        for leaf in jax.tree.leaves(tree):
+            ok = jnp.logical_and(ok, jnp.all(jnp.isfinite(leaf)))
+        return ok
 
 
 def init_ef_state(params: PyTree, dcn_shards: int) -> PyTree:
@@ -245,6 +250,7 @@ def make_batched_meta_train_step(learner: MetaLearner, lite: LiteSpec,
     """
     grads_fn = make_batched_meta_grads(learner, lite)
 
+    @jax.named_scope(OPTIMIZER_SCOPE)
     def apply_update(params, opt_state, loss, acc, grads, ok=None):
         grads, gnorm = clip_by_global_norm(grads, max_grad_norm)
         lr_t = lr if schedule is None else schedule(opt_state["count"])

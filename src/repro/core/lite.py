@@ -299,27 +299,32 @@ def lite_sum(encode_fn: EncodeFn, params: PyTree, xs: PyTree, key: jax.Array,
     if spec.exact or h >= n:
         return reduce_fn(params, (xs, mask), None)
 
-    h_idx, comp_idx = sample_h_indices(key, n, h, mask)
-    take = lambda a, i: jnp.take(a, i, axis=0)
-    xs_h = jax.tree.map(partial(take, i=h_idx), xs)
-    xs_c = jax.tree.map(partial(take, i=comp_idx), xs)
-    w_c = mask[comp_idx]
+    # The named scopes label the three parts in the compiled program's op
+    # names (profiler traces read them); they change no computation.
+    with jax.named_scope("lite.split"):
+        h_idx, comp_idx = sample_h_indices(key, n, h, mask)
+        take = lambda a, i: jnp.take(a, i, axis=0)
+        xs_h = jax.tree.map(partial(take, i=h_idx), xs)
+        xs_c = jax.tree.map(partial(take, i=comp_idx), xs)
+        w_c = mask[comp_idx]
 
     # Differentiable pass over H (single batch — |H| is small by
     # construction).
-    sum_h = reduce_fn(params, (xs_h, mask[h_idx]), None)
+    with jax.named_scope("lite.h_pass"):
+        sum_h = reduce_fn(params, (xs_h, mask[h_idx]), None)
 
     # No-grad pass over the complement, chunked; optionally in low
     # precision (the dominant FLOPs at large N) with fp32 accumulation.
-    frozen = tree_stop_gradient(params)
-    accum = None
-    if spec.compute_dtype is not None:
-        cd = jnp.dtype(spec.compute_dtype)
-        frozen = tree_cast(frozen, cd)
-        xs_c = tree_cast(xs_c, cd)
-        accum = jnp.float32
-    sum_c = _chunked_nograd_reduce(reduce_fn, frozen, xs_c, w_c,
-                                   spec.chunk_size, accum_dtype=accum)
+    with jax.named_scope("lite.complement"):
+        frozen = tree_stop_gradient(params)
+        accum = None
+        if spec.compute_dtype is not None:
+            cd = jnp.dtype(spec.compute_dtype)
+            frozen = tree_cast(frozen, cd)
+            xs_c = tree_cast(xs_c, cd)
+            accum = jnp.float32
+        sum_c = _chunked_nograd_reduce(reduce_fn, frozen, xs_c, w_c,
+                                       spec.chunk_size, accum_dtype=accum)
 
     full = jax.tree.map(lambda a, b: jax.lax.stop_gradient(a + b.astype(a.dtype)),
                         sum_h, sum_c)

@@ -194,8 +194,9 @@ def make_protonets(cfg: MetaLearnerConfig, bb: BackboneDef) -> MetaLearner:
         seg = _sub_seg if estimator == "subsampled" else lite_segment_sum
         protos = _prototypes(params, task.support_x, task.support_y, key,
                              lite, seg, mask=task.support_mask)
-        logits = _logits(params, protos, task.query_x)
-        loss = _xent(logits, task.query_y, task.query_mask)
+        with jax.named_scope("learner.query"):
+            logits = _logits(params, protos, task.query_x)
+            loss = _xent(logits, task.query_y, task.query_mask)
         return loss, dict(
             accuracy=_accuracy(logits, task.query_y, task.query_mask))
 

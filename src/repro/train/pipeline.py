@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -45,14 +46,19 @@ class Prefetcher:
     faults heal in place and the delivered stream is unchanged; only an
     error that survives every retry is re-raised from ``get``.
     ``retries_used`` counts the retries actually spent (surfaced in
-    ``TrainResult.data_retries``).  Always ``close()`` (the training loop
-    does so in a ``finally``) so a preempted run doesn't leak the thread.
+    ``TrainResult.data_retries``); ``wait_s`` sums the seconds (by
+    ``clock``) that ``get`` spent blocked on the worker.  Under the
+    profiler the worker's batch production shows as
+    ``repro.prefetch.produce`` spans and the consumer's waits as
+    ``repro.prefetch.get``.  Always ``close()`` (the training loop does so
+    in a ``finally``) so a preempted run doesn't leak the thread.
     """
 
     def __init__(self, batch_at: Callable[[int], PyTree], start: int,
                  stop: int, depth: int = 2, to_device: bool = True,
                  put: Optional[Callable[[PyTree], PyTree]] = None,
-                 retries: int = 0, backoff_s: float = 0.05):
+                 retries: int = 0, backoff_s: float = 0.05,
+                 clock: Callable[[], float] = time.monotonic):
         """``put`` overrides the default ``jax.device_put`` — pass a
         sharded transfer (e.g. ``device_put`` with a ``NamedSharding``
         over the task axis) so batches land in the mesh layout the
@@ -70,6 +76,8 @@ class Prefetcher:
         self._retries = retries
         self._backoff_s = backoff_s
         self.retries_used = 0
+        self.wait_s = 0.0
+        self._clock = clock
         self._thread = threading.Thread(
             target=self._worker, args=(start, stop), daemon=True,
             name="batch-prefetcher")
@@ -105,9 +113,10 @@ class Prefetcher:
             for s in range(start, stop):
                 if self._stop_evt.is_set():
                     return
-                batch = self._fetch(s)
-                if self._to_device:
-                    batch = self._device_put(batch)
+                with jax.profiler.TraceAnnotation("repro.prefetch.produce"):
+                    batch = self._fetch(s)
+                    if self._to_device:
+                        batch = self._device_put(batch)
                 if not self._put((s, batch)):
                     return
             self._put(_DONE)
@@ -122,16 +131,19 @@ class Prefetcher:
         if step != self._next:
             raise ValueError(f"prefetcher is sequential: expected step "
                              f"{self._next}, got {step}")
-        while True:
-            try:
-                item = self._q.get(timeout=0.1)
-                break
-            except queue.Empty:
-                if self._err is not None:
-                    raise self._err
-                if not self._thread.is_alive():
-                    raise RuntimeError("prefetcher thread died without "
-                                       "delivering a batch")
+        t0 = self._clock()
+        with jax.profiler.TraceAnnotation("repro.prefetch.get"):
+            while True:
+                try:
+                    item = self._q.get(timeout=0.1)
+                    break
+                except queue.Empty:
+                    if self._err is not None:
+                        raise self._err
+                    if not self._thread.is_alive():
+                        raise RuntimeError("prefetcher thread died without "
+                                           "delivering a batch")
+        self.wait_s += self._clock() - t0
         if item is _FAILED:
             raise self._err
         if item is _DONE:
