@@ -16,5 +16,8 @@ intra-chunk), gmm (per-expert grouped GEMM for the MoE dispatch),
 int8_matmul (blocked int8 x f32 matmul with per-block scale applied
 in-kernel and fp32 accumulation — the weight-stationary serving path's
 native site for blockwise-quantized frozen weights; FORWARD-ONLY by
-contract, no custom_vjp: serving never differentiates through it).
+contract, no custom_vjp: serving never differentiates through it),
+conv_pool (the backbone's image block, conv3x3 + ReLU + 2x2 max-pool in
+one pass; the backbone calls it through a custom_vjp whose backward is
+the XLA composite's).
 """

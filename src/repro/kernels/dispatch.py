@@ -105,11 +105,19 @@ def use_backend(backend: Optional[str]):
             _default_backend.reset(token)
 
 
+def pallas_native() -> bool:
+    """The platform policy: True where Pallas kernels lower to Mosaic (TPU),
+    False where they would run interpreted.  ``auto`` resolves by it, and
+    so do the kernels chosen by platform and shape alone (the backbone's
+    fused image block)."""
+    return not _interpret()
+
+
 def resolve_backend(backend: Optional[str] = None) -> str:
     """None -> context default; ``auto`` -> pallas on TPU else ref."""
     b = _check(_default_backend.get() if backend is None else backend)
     if b == "auto":
-        return "ref" if _interpret() else "pallas"
+        return "pallas" if pallas_native() else "ref"
     return b
 
 

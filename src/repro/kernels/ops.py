@@ -38,6 +38,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import conv_pool as _cp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import gmm as _gmm
 from repro.kernels import mahalanobis as _md
@@ -105,3 +106,11 @@ def gmm(x, w):
 def ssd_chunk(x, dt, A, B, C):
     """Intra-chunk SSD (see repro.kernels.ssd_scan)."""
     return _ssd.ssd_chunk(x, dt, A, B, C, interpret=_interpret())
+
+
+@jax.jit
+def conv3x3_relu_pool(x, w, b):
+    """x: (B, H, W, cin), H and W even; w: (3, 3, cin, cout); b: (cout,)
+    -> maxpool2(relu(conv3x3_same(x, w) + b)), the pre-pool activation
+    kept on chip (see repro.kernels.conv_pool)."""
+    return _cp.conv3x3_relu_pool(x, w, b, interpret=_interpret())

@@ -7,10 +7,17 @@ TPU and an 84x84 / reduced regime on CPU tests).
 Blocks: conv3x3 -> FiLM -> relu -> maxpool2 (the classic few-shot "Conv-N"
 family, which the paper's small-image baselines use), plus an optional
 channel-expanding stem matching EfficientNet-ish widths.
+
+On TPU the image block (few input channels, even H and W, no FiLM, a
+batch that fills at least half of the kernel's 128 lanes) runs
+forward-only passes as one fused Pallas kernel
+(:mod:`repro.kernels.conv_pool`), so its full-resolution pre-pool
+activation never reaches HBM; differentiated calls keep the XLA composite.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -18,7 +25,7 @@ import jax.numpy as jnp
 
 from repro.common.init import conv_lecun_normal, lecun_normal
 from repro.core.film import apply_film
-from repro.kernels import dispatch
+from repro.kernels import conv_pool, dispatch, ops
 from repro.models.backbone import BackboneDef
 
 
@@ -45,20 +52,63 @@ def init_conv_backbone(key: jax.Array, cfg: ConvBackboneConfig) -> Dict:
     return params
 
 
+def _conv_block(h: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
+                film: Optional[Dict]) -> jnp.ndarray:
+    """conv3x3 (SAME) + bias -> FiLM -> relu -> maxpool2, in XLA."""
+    h = jax.lax.conv_general_dilated(
+        h, w, window_strides=(1, 1), padding="SAME",
+        dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
+    if film is not None:
+        h = apply_film(h, film["gamma"], film["beta"], channel_axis=-1)
+    h = jax.nn.relu(h)
+    if h.shape[1] >= 2 and h.shape[2] >= 2:
+        h = jax.lax.reduce_window(
+            h, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+    return h
+
+
+@jax.custom_vjp
+def _image_block(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray):
+    """The FiLM-free image block.  A call none of whose inputs carries a
+    tangent (the LITE complement, the finetuner's frozen features) runs
+    the primal, the fused kernel; a differentiated call runs
+    ``_image_block_fwd``, the XLA composite and its VJP."""
+    return ops.conv3x3_relu_pool(x, w, b)
+
+
+def _image_block_fwd(x, w, b):
+    return jax.vjp(functools.partial(_conv_block, film=None), x, w, b)
+
+
+def _image_block_bwd(vjp_fn, g):
+    return vjp_fn(g)
+
+
+_image_block.defvjp(_image_block_fwd, _image_block_bwd)
+
+
+def _fuses(h: jnp.ndarray, film: Optional[Dict]) -> bool:
+    """The fused kernel takes an image (<= 4 channels) with even H and W
+    and no FiLM (a negative FiLM scale would not commute with the max),
+    on TPU only, and a batch of at least half its lanes: it pads the batch
+    to whole 128-image lane blocks, so smaller calls (serving's query and
+    adapt chunks) would pay for lanes they leave empty."""
+    return (film is None and h.shape[-1] <= 4 and h.shape[1] % 2 == 0
+            and h.shape[2] % 2 == 0
+            and h.shape[0] >= conv_pool.LANES // 2
+            and dispatch.pallas_native())
+
+
 def conv_features(params: Dict, x: jnp.ndarray, film: Optional[List[Dict]],
                   cfg: ConvBackboneConfig) -> jnp.ndarray:
     """x: (B, H, W, C) -> (B, feature_dim). One FiLM site per block."""
     h = x
     for i, blk in enumerate(params["blocks"]):
-        h = jax.lax.conv_general_dilated(
-            h, blk["w"], window_strides=(1, 1), padding="SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC")) + blk["b"]
-        if film is not None:
-            h = apply_film(h, film[i]["gamma"], film[i]["beta"], channel_axis=-1)
-        h = jax.nn.relu(h)
-        if h.shape[1] >= 2 and h.shape[2] >= 2:
-            h = jax.lax.reduce_window(
-                h, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+        site = None if film is None else film[i]
+        if _fuses(h, site):
+            h = _image_block(h, blk["w"], blk["b"])
+        else:
+            h = _conv_block(h, blk["w"], blk["b"], site)
     h = jnp.mean(h, axis=(1, 2))
     w = params["head"]["w"]
     if isinstance(w, dict):

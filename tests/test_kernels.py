@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import dispatch, ops, ref
+from repro.models import conv_backbone
 from repro.kernels.flash_attention import flash_attention as fa_raw
 
 
@@ -67,6 +68,55 @@ def test_segment_pool_sweep(key, b, f, c):
     s2, c2 = ref.segment_pool_ref(x, y, c)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-4)
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
+
+
+def _bf16_exact(a):
+    return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+@pytest.mark.parametrize("b,h,w,cin,cout", [
+    (3, 16, 12, 3, 64), (130, 8, 6, 3, 32), (2, 6, 10, 1, 8)])
+def test_conv3x3_relu_pool_sweep(key, b, h, w, cin, cout):
+    """The fused image block against the XLA composite (conv3x3 SAME +
+    bias, ReLU, 2x2 max-pool), operands pre-rounded to bfloat16 so the
+    kernel's rounding of them is exact; the bias has negative entries,
+    and 130 images span two 128-lane blocks."""
+    x = _bf16_exact(jax.random.normal(key, (b, h, w, cin)))
+    wt = _bf16_exact(0.3 * jax.random.normal(jax.random.fold_in(key, 1),
+                                             (3, 3, cin, cout)))
+    bias = jax.random.normal(jax.random.fold_in(key, 2), (cout,))
+    assert float(jnp.min(bias)) < 0
+    got = ops.conv3x3_relu_pool(x, wt, bias)
+    want = conv_backbone._conv_block(x, wt, bias, None)
+    assert got.shape == want.shape == (b, h // 2, w // 2, cout)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case,fused", [
+    ("image", True), ("odd_h", False), ("odd_w", False), ("film", False),
+    ("cin_5", False), ("batch_8", False), ("batch_32", False),
+    ("batch_1000", True)])
+def test_conv3x3_relu_pool_engages_on_image_blocks_only(monkeypatch, case,
+                                                         fused):
+    """With the platform policy forced to TPU the backbone fuses a
+    FiLM-free block of an image (<= 4 channels) with even H and W and a
+    batch of at least half the kernel's 128 lanes, and only that block;
+    every other block, and a smaller batch (serving's query chunk of 8,
+    its adapt chunk of 32), keeps the composite."""
+    monkeypatch.setattr(dispatch, "pallas_native", lambda: True)
+    cin = 5 if case == "cin_5" else 3
+    cfg = conv_backbone.ConvBackboneConfig(in_channels=cin, widths=(8, 8),
+                                           feature_dim=4)
+    params = conv_backbone.init_conv_backbone(jax.random.key(0), cfg)
+    film = ([dict(gamma=jnp.zeros(8), beta=jnp.zeros(8))] * 2
+            if case == "film" else None)
+    batch = int(case[6:]) if case.startswith("batch_") else 64
+    x = jnp.zeros((batch, 7 if case == "odd_h" else 8,
+                   9 if case == "odd_w" else 8, cin))
+    jaxpr = str(jax.make_jaxpr(
+        lambda x: conv_backbone.conv_features(params, x, film, cfg))(x))
+    assert jaxpr.count("pallas_call") == (1 if fused else 0)
 
 
 @pytest.mark.parametrize("e,c,d,f,dtype", [

@@ -4,7 +4,9 @@ Nothing runs: each kernel is lowered and compiled for one chip of a
 described (not attached) ``v5e:2x2`` topology, which is what Mosaic
 refuses or accepts — block tiling, VMEM limits — and interpret mode cannot
 show.  Widths are the chip smoke's: B=1000 support images, C=10 classes,
-F=256 features, and a K=N=256 int8 head.
+F=256 features, and a K=N=256 int8 head; the fused image block takes the
+Conv-4 cell's complement chunk, 100 images of 224x224x3 into 64 filters,
+alone and vmapped over two tasks, and a whole 1000-image support set.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test workers all
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import conv_pool as cp
 from repro.kernels import int8_matmul as im
 from repro.kernels import mahalanobis as md
 from repro.kernels import segment_pool as sp
@@ -63,6 +66,21 @@ CASES = {
         lambda x, q, s: im.int8_matmul(x, q, s),
         [((B, F), jnp.float32), ((F, F), jnp.int8),
          ((F, F // 128), jnp.float32)]),
+    "conv3x3_relu_pool": (
+        lambda x, w, b: cp.conv3x3_relu_pool(x, w, b),
+        [((100, 224, 224, 3), jnp.float32), ((3, 3, 3, 64), jnp.float32),
+         ((64,), jnp.float32)]),
+    # a whole 1000-image support set: 8 lane blocks, the last one partial
+    "conv3x3_relu_pool_b1000": (
+        lambda x, w, b: cp.conv3x3_relu_pool(x, w, b),
+        [((B, 224, 224, 3), jnp.float32), ((3, 3, 3, 64), jnp.float32),
+         ((64,), jnp.float32)]),
+    # the train step vmaps the complement over two tasks
+    "conv3x3_relu_pool_vmap": (
+        jax.vmap(lambda x, w, b: cp.conv3x3_relu_pool(x, w, b),
+                 in_axes=(0, None, None)),
+        [((2, 100, 224, 224, 3), jnp.float32), ((3, 3, 3, 64), jnp.float32),
+         ((64,), jnp.float32)]),
 }
 
 
