@@ -1,0 +1,15 @@
+"""Device time of the Simple CNAPs set encoder per task (ms): the traced
+steps' ops under the program's ``cnaps.set_encoder`` scope, forward and
+backward, the LITE split, H pass and complement of the task embedding
+included (``bench/harness/train_film.py`` ``scope_seconds``)."""
+
+SCOPES = ("cnaps.set_encoder",)
+
+
+def read(obs):
+    t = obs.get("trace") or {}
+    sc = t.get("scopes")
+    if not sc or not any(k in sc for k in SCOPES):
+        return None
+    return 1e3 * sum(sc.get(k, 0.0) for k in SCOPES) / (
+        t["steps"] * obs["tasks_per_step"])

@@ -109,7 +109,8 @@ def _rel(a, b) -> float:
 
 
 def shape_argv(shape: EpisodicShape) -> list:
-    argv = ["--widths", ",".join(str(w) for w in shape.widths),
+    argv = ["--backbone", shape.backbone,
+            "--widths", ",".join(str(w) for w in shape.widths),
             "--feature-dim", str(shape.feature_dim), "--way", str(shape.way),
             "--shot", str(shape.shot),
             "--query-per-class", str(shape.query_per_class),

@@ -292,32 +292,41 @@ def _make_cnaps_family(cfg: MetaLearnerConfig, bb: BackboneDef,
     def _configure(params, sx, sy, key, lite: LiteSpec,
                    sum_estimator=lite_sum, stats_mode="lite",
                    mask=None):
-        """Support set -> task_state (film + head statistics)."""
-        z = _task_embedding(params, sx, key, lite, sum_estimator, mask=mask)
-        film = generate_film_params(params["film_gen"], z)
-        sums, counts = _class_stats(params, film, sx, sy, key, lite,
-                                    stats_mode, mask=mask)
+        """Support set -> task_state (film + head statistics).  The named
+        scopes label each part in the compiled program's op names
+        (profiler traces read them); they change no computation."""
+        with jax.named_scope("cnaps.set_encoder"):
+            z = _task_embedding(params, sx, key, lite, sum_estimator,
+                                mask=mask)
+        with jax.named_scope("cnaps.film_generator"):
+            film = generate_film_params(params["film_gen"], z)
+        with jax.named_scope("cnaps.class_stats"):
+            sums, counts = _class_stats(params, film, sx, sy, key, lite,
+                                        stats_mode, mask=mask)
         k_c = jnp.maximum(counts, 1.0)
         mu = sums["feat"] / k_c[:, None]                       # (C, F)
         state = dict(film=film, mu=mu)
         if simple:
-            # Simple CNAPs Mahalanobis statistics (paper Eq. in §3.1):
-            # Sigma_c = l_c * S_c + (1 - l_c) * S_task + eps*I, l_c = k/(k+1)
-            ex2 = sums["outer"] / k_c[:, None, None]
-            cov_c = ex2 - mu[:, :, None] * mu[:, None, :]
-            n_tot = jnp.maximum(jnp.sum(counts), 1.0)
-            mu_t = jnp.sum(sums["feat"], 0) / n_tot
-            ex2_t = jnp.sum(sums["outer"], 0) / n_tot
-            cov_t = ex2_t - jnp.outer(mu_t, mu_t)
-            lam = (k_c / (k_c + 1.0))[:, None, None]
-            sigma = lam * cov_c + (1.0 - lam) * cov_t[None]
-            # scale-aware ridge: cov_eps plus a fraction of the mean
-            # diagonal, so f32 cancellation in E[xx^T] - mu mu^T can never
-            # push eigenvalues below the jitter (cholesky would NaN).
-            diag_mean = jnp.mean(jax.vmap(jnp.diag)(sigma), axis=-1)
-            eps = cfg.cov_eps + 1e-3 * jnp.maximum(diag_mean, 0.0)
-            sigma = sigma + eps[:, None, None] * jnp.eye(fdim)[None]
-            state["chol"] = jax.vmap(jnp.linalg.cholesky)(sigma)
+            with jax.named_scope("cnaps.covariance"):
+                # Simple CNAPs Mahalanobis statistics (paper Eq. in §3.1):
+                # Sigma_c = l_c * S_c + (1 - l_c) * S_task + eps*I,
+                # l_c = k/(k+1)
+                ex2 = sums["outer"] / k_c[:, None, None]
+                cov_c = ex2 - mu[:, :, None] * mu[:, None, :]
+                n_tot = jnp.maximum(jnp.sum(counts), 1.0)
+                mu_t = jnp.sum(sums["feat"], 0) / n_tot
+                ex2_t = jnp.sum(sums["outer"], 0) / n_tot
+                cov_t = ex2_t - jnp.outer(mu_t, mu_t)
+                lam = (k_c / (k_c + 1.0))[:, None, None]
+                sigma = lam * cov_c + (1.0 - lam) * cov_t[None]
+                # scale-aware ridge: cov_eps plus a fraction of the mean
+                # diagonal, so f32 cancellation in E[xx^T] - mu mu^T can
+                # never push eigenvalues below the jitter (cholesky would
+                # NaN).
+                diag_mean = jnp.mean(jax.vmap(jnp.diag)(sigma), axis=-1)
+                eps = cfg.cov_eps + 1e-3 * jnp.maximum(diag_mean, 0.0)
+                sigma = sigma + eps[:, None, None] * jnp.eye(fdim)[None]
+                state["chol"] = jax.vmap(jnp.linalg.cholesky)(sigma)
         else:
             h = jax.nn.relu(mu @ params["head_gen"]["w1"] + params["head_gen"]["b1"])
             wb = h @ params["head_gen"]["w2"] + params["head_gen"]["b2"]
@@ -344,8 +353,9 @@ def _make_cnaps_family(cfg: MetaLearnerConfig, bb: BackboneDef,
         state = _configure(params, task.support_x, task.support_y, key, lite,
                            sum_est, "subsampled" if sub else "lite",
                            mask=task.support_mask)
-        logits = _logits(params, state, task.query_x)
-        loss = _xent(logits, task.query_y, task.query_mask)
+        with jax.named_scope("learner.query"):
+            logits = _logits(params, state, task.query_x)
+            loss = _xent(logits, task.query_y, task.query_mask)
         return loss, dict(
             accuracy=_accuracy(logits, task.query_y, task.query_mask))
 

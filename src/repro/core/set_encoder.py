@@ -4,7 +4,10 @@ Encodes a support set into a permutation-invariant task embedding by MEAN
 pooling per-example encodings — the aggregation site LITE subsamples.
 
 Three variants:
-  * conv   — small conv net for image supports (paper's encoder).
+  * conv   — small conv net for image supports (paper's encoder): the
+    conv backbone of :mod:`repro.models.conv_backbone` at width
+    ``conv_width`` x ``conv_blocks`` with a ``task_dim`` head, so the
+    set encoder's image block takes the same fused kernel on TPU.
   * mlp    — for pre-featurized supports (modality-stub embeddings).
   * tokens — bag-of-tokens: normalized token histogram -> MLP, for the
     episodic-LM integration (support examples are token sequences).
@@ -20,7 +23,9 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from repro.common.init import conv_lecun_normal, lecun_normal
+from repro.common.init import lecun_normal
+from repro.models.conv_backbone import (ConvBackboneConfig, conv_features,
+                                        init_conv_backbone)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,20 +38,15 @@ class SetEncoderConfig:
     mlp_hidden: int = 128
 
 
+def _conv_config(cfg: SetEncoderConfig) -> ConvBackboneConfig:
+    return ConvBackboneConfig(in_channels=cfg.in_channels,
+                              widths=(cfg.conv_width,) * cfg.conv_blocks,
+                              feature_dim=cfg.task_dim)
+
+
 def init_set_encoder(key: jax.Array, cfg: SetEncoderConfig) -> Dict:
     if cfg.kind == "conv":
-        params = dict(blocks=[])
-        ch = cfg.in_channels
-        keys = jax.random.split(key, cfg.conv_blocks + 1)
-        for i in range(cfg.conv_blocks):
-            params["blocks"].append(
-                dict(w=conv_lecun_normal(keys[i], (3, 3, ch, cfg.conv_width)),
-                     b=jnp.zeros((cfg.conv_width,)))
-            )
-            ch = cfg.conv_width
-        params["head"] = dict(w=lecun_normal(keys[-1], (ch, cfg.task_dim)),
-                              b=jnp.zeros((cfg.task_dim,)))
-        return params
+        return init_conv_backbone(key, _conv_config(cfg))
     if cfg.kind in ("mlp", "tokens"):
         k1, k2 = jax.random.split(key)
         return dict(
@@ -58,24 +58,10 @@ def init_set_encoder(key: jax.Array, cfg: SetEncoderConfig) -> Dict:
     raise ValueError(f"unknown set encoder kind: {cfg.kind}")
 
 
-def _conv(x, w, b):
-    y = jax.lax.conv_general_dilated(
-        x, w, window_strides=(1, 1), padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    return y + b
-
-
 def encode_set(params: Dict, x: jnp.ndarray, cfg: SetEncoderConfig) -> jnp.ndarray:
     """Per-example encodings (B, task_dim). No pooling here — LITE pools."""
     if cfg.kind == "conv":
-        h = x
-        for blk in params["blocks"]:
-            h = _conv(h, blk["w"], blk["b"])
-            h = jax.nn.relu(h)
-            h = jax.lax.reduce_window(
-                h, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-        h = jnp.mean(h, axis=(1, 2))  # global average pool -> (B, ch)
-        return h @ params["head"]["w"] + params["head"]["b"]
+        return conv_features(params, x, None, _conv_config(cfg))
     if cfg.kind in ("mlp", "tokens"):
         if cfg.kind == "tokens":
             # (B, S) int ids -> normalized histogram over the vocab

@@ -136,3 +136,31 @@ def test_prefetcher_spans_reach_the_profiler_trace(tmp_path):
         jax.profiler.stop_trace()
     names = {n for _, _, n in layers.load(str(tmp_path))["host"]}
     assert {"repro.prefetch.produce", "repro.prefetch.get"} <= names
+
+
+CNAPS_SCOPES = ("cnaps.set_encoder", "cnaps.film_generator",
+                "cnaps.class_stats", "cnaps.covariance", "learner.query")
+
+
+def test_cnaps_scopes_reach_the_compiled_step(tmp_path):
+    """Simple CNAPs names its set encoder, FiLM generator, class
+    statistics, covariance and query path; LITE's scopes nest inside the
+    two aggregation sites, and the complement stays forward only."""
+    args = launcher.build_parser().parse_args(
+        ["--episodic", "--learner", "simple_cnaps", "--steps", "4",
+         "--tasks-per-step", "1", "--data-source", "device",
+         "--widths", "4,8", "--feature-dim", "16", "--way", "3",
+         "--shot", "4", "--query-per-class", "2", "--image-size", "16",
+         "--lite-h", "3", "--lite-chunk", "4", "--ckpt-dir", str(tmp_path)])
+    job = launcher.build_episodic(args)
+    hlo = jax.jit(job.step).lower(job.state, job.batch_at(0)).compile()
+    names = set(_OP_NAME.findall(hlo.as_text()))
+    for scope in CNAPS_SCOPES:
+        assert any(scope in n for n in names), scope
+    for site in ("cnaps.set_encoder", "cnaps.class_stats"):
+        for part in ("lite.split", "lite.h_pass", "lite.complement"):
+            assert any(re.search(re.escape(site) + r"\)*/" + part, n)
+                       for n in names), (site, part)
+    bwd = [n[n.index("transpose("):] for n in names if "transpose(" in n]
+    assert any("cnaps.class_stats" in n and "lite.h_pass" in n for n in bwd)
+    assert not any("lite.complement" in n for n in bwd)

@@ -8,6 +8,11 @@ F=256 features, and a K=N=256 int8 head; the fused image block takes the
 Conv-4 cell's complement chunk, 100 images of 224x224x3 into 64 filters,
 alone and vmapped over two tasks, and a whole 1000-image support set.
 
+The Simple CNAPs + EfficientNet-B0 LITE step of the benchmark cell (224
+px, way 10 x shot 100, 100 queries, |H| 40, chunk 100, F = 1280) compiles
+whole through the launcher's ``build_episodic``, with the set encoder's
+fused image block, and fits one chip's 16 GB.
+
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test workers all
 import this file.  The persistent compilation cache is off around these
@@ -90,3 +95,29 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
               for s, dt in specs]
     assert "tpu_custom_call" in _compile_hlo(fn, *shapes)
+
+
+def test_simple_cnaps_efficientnet_step_compiles_for_v5e(one_chip, monkeypatch,
+                                                        tmp_path):
+    from repro.kernels import dispatch, ops
+    from repro.launch import train as launcher
+
+    monkeypatch.setattr(dispatch, "pallas_native", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    args = launcher.build_parser().parse_args(
+        ["--episodic", "--learner", "simple_cnaps", "--backbone",
+         "efficientnet_b0", "--tasks-per-step", "1", "--way", "10",
+         "--shot", "100", "--query-per-class", "10", "--image-size", "224",
+         "--lite-h", "40", "--lite-chunk", "100", "--ckpt-dir",
+         str(tmp_path)])
+    job = launcher.build_episodic(args)
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    state = jax.tree.map(on_chip, job.state_abs)
+    batch = jax.tree.map(on_chip, jax.eval_shape(job.batch_at, 0))
+    compiled = jax.jit(job.step).lower(state, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
