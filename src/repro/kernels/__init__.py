@@ -18,6 +18,7 @@ in-kernel and fp32 accumulation — the weight-stationary serving path's
 native site for blockwise-quantized frozen weights; FORWARD-ONLY by
 contract, no custom_vjp: serving never differentiates through it),
 conv_pool (the backbone's image block, conv3x3 + ReLU + 2x2 max-pool in
-one pass; the backbone calls it through a custom_vjp whose backward is
-the XLA composite's).
+one pass, and its weight and bias gradient in another; the backbone
+calls them through a custom_vjp, which keeps the XLA composite's VJP for
+an image that carries a tangent).
 """

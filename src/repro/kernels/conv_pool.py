@@ -26,6 +26,18 @@ operands (one array under index maps one row apart), so no halo is needed.
 Precision is XLA's DEFAULT on TPU: matmul operands rounded to bfloat16,
 float32 accumulation, and bias, max, ReLU and the output in the input's
 float dtype.
+
+``conv3x3_relu_pool_bwd`` is the weight and bias gradient of the same
+block, for a pooled cotangent ``g``.  It reads the same s2d image and
+phase weight, recomputes the four phases of each pooled pixel with the
+forward's matmul, and routes ``g`` to the first phase that attains the
+max in window order (0,0), (0,1), (1,0), (1,1) (XLA's select-and-scatter
+tie rule for a max-pool's gradient), where the pooled pre-activation is
+positive.  The routed cotangent, split into two bfloat16 parts, meets
+the same columns in two more matmuls, which accumulate the phase
+weight's gradient in float32; folding that back to ``(3, 3, cin, cout)``
+is the transpose of ``_phase_weights``.  Neither the pre-pool activation
+nor its gradient leaves the chip.
 """
 from __future__ import annotations
 
@@ -79,6 +91,22 @@ def _space_to_depth(x: jnp.ndarray, kc: int, bp: int) -> jnp.ndarray:
     return s.astype(jnp.bfloat16)
 
 
+def _group(w2: int) -> int:
+    """Pooled pixels per matmul: the most, up to PIXELS_PER_DOT, that
+    divide the pooled row."""
+    return max(d for d in range(1, min(PIXELS_PER_DOT, w2) + 1)
+               if w2 % d == 0)
+
+
+def _columns(top_ref, bot_ref, j0, group: int) -> jnp.ndarray:
+    """(4 kc, group * LANES): pooled pixels j0 .. j0 + group - 1 of a row
+    side by side, each the s2d taps (0,0), (0,1), (1,0), (1,1)."""
+    return jnp.concatenate(
+        [jnp.concatenate([top_ref[0, j0 + k], top_ref[0, j0 + k + 1],
+                          bot_ref[0, j0 + k], bot_ref[0, j0 + k + 1]],
+                         axis=0) for k in range(group)], axis=1)
+
+
 def _kernel(wt_ref, b_ref, top_ref, bot_ref, o_ref, *, cout: int,
             group: int, lanes_out: int):
     wt = wt_ref[...]                                   # (4 cout, 4 kc) bf16
@@ -86,11 +114,7 @@ def _kernel(wt_ref, b_ref, top_ref, bot_ref, o_ref, *, cout: int,
 
     def body(g, carry):
         j0 = g * group
-        # (4 kc, LANES) per pooled pixel: taps (0,0), (0,1), (1,0), (1,1)
-        cols = [jnp.concatenate([top_ref[0, j0 + k], top_ref[0, j0 + k + 1],
-                                 bot_ref[0, j0 + k], bot_ref[0, j0 + k + 1]],
-                                axis=0) for k in range(group)]
-        y = jnp.dot(wt, jnp.concatenate(cols, axis=1),
+        y = jnp.dot(wt, _columns(top_ref, bot_ref, j0, group),
                     preferred_element_type=jnp.float32)
         m = jnp.maximum(jnp.maximum(y[:cout], y[cout:2 * cout]),
                         jnp.maximum(y[2 * cout:3 * cout], y[3 * cout:]))
@@ -112,8 +136,7 @@ def conv3x3_relu_pool(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
     kc = _round_up(4 * cin, 16)
     bp = _round_up(bsz, LANES)
     lanes_out = min(bsz, LANES)
-    group = max(d for d in range(1, min(PIXELS_PER_DOT, w2) + 1)
-                if w2 % d == 0)
+    group = _group(w2)
     s = _space_to_depth(x, kc, bp)
     wt = _phase_weights(w, kc).astype(jnp.bfloat16)
     bias = b.astype(jnp.float32).reshape(cout, 1)
@@ -137,3 +160,99 @@ def conv3x3_relu_pool(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
         name="conv3x3_relu_pool",
     )(wt, bias, s, s)
     return out.transpose(3, 0, 1, 2)
+
+
+def _bwd_kernel(wt_ref, b_ref, top_ref, bot_ref, g_ref, dwt_ref, db_ref, *,
+                cout: int, group: int, batch: int):
+    n, i = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((n == 0) & (i == 0))
+    def _():
+        dwt_ref[...] = jnp.zeros_like(dwt_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    wt = wt_ref[...]                                   # (4 cout, 4 kc) bf16
+    bias = b_ref[...]                                  # (cout, 1) f32
+    # a partial last lane block of the cotangent reads past the batch
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, group * LANES), 1)
+    real = n * LANES + lane % LANES < batch
+
+    def body(k0, carry):
+        dwt, db = carry
+        j0 = k0 * group
+        c = _columns(top_ref, bot_ref, j0, group)
+        y = jnp.dot(wt, c, preferred_element_type=jnp.float32)
+        y0, y1 = y[:cout], y[cout:2 * cout]
+        y2, y3 = y[2 * cout:3 * cout], y[3 * cout:]
+        m01, m23 = jnp.maximum(y0, y1), jnp.maximum(y2, y3)
+        g = jnp.concatenate([g_ref[0, j0 + k] for k in range(group)], axis=1)
+        live = jnp.maximum(m01, m23) + bias > 0
+        g = jnp.where(live & real if batch % LANES else live, g, 0.0)
+        # the first phase at the max (the bias, common to all four, left
+        # out): a pair's first wins a tie within it, the first pair a tie
+        # between the pairs
+        left, first01, first23 = m01 >= m23, y0 >= y1, y2 >= y3
+        gl = jnp.where(left, g, 0.0)
+        gr = jnp.where(left, 0.0, g)
+        dy = jnp.concatenate(
+            [jnp.where(first01, gl, 0.0), jnp.where(first01, 0.0, gl),
+             jnp.where(first23, gr, 0.0), jnp.where(first23, 0.0, gr)],
+            axis=0)
+        # the cotangent as the sum of two bfloat16 parts, 16 bits of its
+        # mantissa: a float32 cotangent then loses little more than the
+        # image's own rounding (the columns are bfloat16 already)
+        hi = dy.astype(jnp.bfloat16)
+        lo = (dy - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        for part in (hi, lo):
+            dwt = dwt + jax.lax.dot_general(
+                part, c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        for k in range(group):
+            db = db + g[:, k * LANES:(k + 1) * LANES]
+        return dwt, db
+
+    dwt, db = jax.lax.fori_loop(
+        0, g_ref.shape[1] // group, body,
+        (jnp.zeros(dwt_ref.shape, jnp.float32),
+         jnp.zeros(db_ref.shape, jnp.float32)))
+    dwt_ref[...] += dwt
+    db_ref[...] += db
+
+
+def conv3x3_relu_pool_bwd(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
+                          g: jnp.ndarray, *, interpret: bool = False):
+    """The weight and bias gradients of ``conv3x3_relu_pool(x, w, b)`` for
+    the pooled cotangent g: (B, H/2, W/2, cout) -> (dw (3, 3, cin, cout),
+    db (cout,)), in w's and b's dtypes."""
+    bsz, h, wd, cin = x.shape
+    cout = w.shape[-1]
+    h2, w2 = h // 2, wd // 2
+    kc = _round_up(4 * cin, 16)
+    bp = _round_up(bsz, LANES)
+    group = _group(w2)
+    s = _space_to_depth(x, kc, bp)
+    wt, fold = jax.vjp(lambda w: _phase_weights(w, kc), w)
+    bias = b.astype(jnp.float32).reshape(cout, 1)
+    row = (1, w2 + 1, kc, LANES)
+    const = lambda n, i: (0, 0)
+    dwt, db = pl.pallas_call(
+        functools.partial(_bwd_kernel, cout=cout, group=group, batch=bsz),
+        grid=(bp // LANES, h2),
+        in_specs=[
+            pl.BlockSpec((4 * cout, 4 * kc), const),
+            pl.BlockSpec((cout, 1), const),
+            pl.BlockSpec(row, lambda n, i: (i, 0, 0, n)),
+            pl.BlockSpec(row, lambda n, i: (i + 1, 0, 0, n)),
+            pl.BlockSpec((1, w2, cout, LANES), lambda n, i: (i, 0, 0, n)),
+        ],
+        out_specs=[pl.BlockSpec((4 * cout, 4 * kc), const),
+                   pl.BlockSpec((cout, LANES), const)],
+        out_shape=[jax.ShapeDtypeStruct((4 * cout, 4 * kc), jnp.float32),
+                   jax.ShapeDtypeStruct((cout, LANES), jnp.float32)],
+        compiler_params=CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="conv3x3_relu_pool_bwd",
+    )(wt.astype(jnp.bfloat16), bias, s, s, g.transpose(1, 2, 3, 0))
+    dw, = fold(dwt.astype(wt.dtype))
+    return dw, jnp.sum(db, axis=1).astype(b.dtype)

@@ -114,3 +114,11 @@ def conv3x3_relu_pool(x, w, b):
     -> maxpool2(relu(conv3x3_same(x, w) + b)), the pre-pool activation
     kept on chip (see repro.kernels.conv_pool)."""
     return _cp.conv3x3_relu_pool(x, w, b, interpret=_interpret())
+
+
+@jax.jit
+def conv3x3_relu_pool_bwd(x, w, b, g):
+    """The weight and bias gradients of ``conv3x3_relu_pool(x, w, b)`` for
+    the pooled cotangent g, the pre-pool activation and its gradient kept
+    on chip (see repro.kernels.conv_pool)."""
+    return _cp.conv3x3_relu_pool_bwd(x, w, b, g, interpret=_interpret())

@@ -9,10 +9,10 @@ family, which the paper's small-image baselines use), plus an optional
 channel-expanding stem matching EfficientNet-ish widths.
 
 On TPU the image block (few input channels, even H and W, no FiLM, a
-batch that fills at least half of the kernel's 128 lanes) runs
-forward-only passes as one fused Pallas kernel
-(:mod:`repro.kernels.conv_pool`), so its full-resolution pre-pool
-activation never reaches HBM; differentiated calls keep the XLA composite.
+batch that fills at least a quarter of the kernel's 128 lanes) runs as
+fused Pallas kernels (:mod:`repro.kernels.conv_pool`), forward and, for
+the weights and bias, backward, so neither its full-resolution pre-pool
+activation nor that activation's gradient reaches HBM.
 """
 from __future__ import annotations
 
@@ -69,33 +69,39 @@ def _conv_block(h: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
 
 @jax.custom_vjp
 def _image_block(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray):
-    """The FiLM-free image block.  A call none of whose inputs carries a
-    tangent (the LITE complement, the finetuner's frozen features) runs
-    the primal, the fused kernel; a differentiated call runs
-    ``_image_block_fwd``, the XLA composite and its VJP."""
+    """The FiLM-free image block: the fused kernel forward, and for the
+    weights and bias (the H pass, the query path) the fused kernel
+    backward.  A call whose image carries a tangent takes the XLA
+    composite and its VJP instead, so the input gradient is exact."""
     return ops.conv3x3_relu_pool(x, w, b)
 
 
 def _image_block_fwd(x, w, b):
-    return jax.vjp(functools.partial(_conv_block, film=None), x, w, b)
+    if x.perturbed:
+        return jax.vjp(functools.partial(_conv_block, film=None),
+                       x.value, w.value, b.value)
+    x, w, b = x.value, w.value, b.value
+    return ops.conv3x3_relu_pool(x, w, b), (x, w, b)
 
 
-def _image_block_bwd(vjp_fn, g):
-    return vjp_fn(g)
+def _image_block_bwd(res, g):
+    if not isinstance(res, tuple):    # the composite's VJP
+        return res(g)
+    return (None, *ops.conv3x3_relu_pool_bwd(*res, g))
 
 
-_image_block.defvjp(_image_block_fwd, _image_block_bwd)
+_image_block.defvjp(_image_block_fwd, _image_block_bwd, symbolic_zeros=True)
 
 
 def _fuses(h: jnp.ndarray, film: Optional[Dict]) -> bool:
     """The fused kernel takes an image (<= 4 channels) with even H and W
     and no FiLM (a negative FiLM scale would not commute with the max),
-    on TPU only, and a batch of at least half its lanes: it pads the batch
-    to whole 128-image lane blocks, so smaller calls (serving's query and
-    adapt chunks) would pay for lanes they leave empty."""
+    on TPU only, and a batch of at least a quarter of its lanes: it pads
+    the batch to whole 128-image lane blocks, so smaller calls (serving's
+    query chunks) would pay for lanes they leave empty."""
     return (film is None and h.shape[-1] <= 4 and h.shape[1] % 2 == 0
             and h.shape[2] % 2 == 0
-            and h.shape[0] >= conv_pool.LANES // 2
+            and h.shape[0] >= conv_pool.LANES // 4
             and dispatch.pallas_native())
 
 

@@ -1,12 +1,13 @@
 """Where the fused image block engages in the LITE training step.
 
 On TPU the backbone's first block (conv3x3 + bias, ReLU, 2x2 max-pool)
-runs as one Pallas kernel (``repro.kernels.conv_pool``) in calls that
-carry no tangent and hold at least 64 images, and as the XLA composite
-everywhere else.  Here the
-platform policy is forced to TPU (the kernel then runs in interpret mode
-on the CPU) and the launcher's episodic ProtoNets step is compiled at a
-tiny size."""
+runs as one Pallas kernel (``repro.kernels.conv_pool``) in every call of
+at least the kernel's floor of images: the forward ``conv3x3_relu_pool``
+in the complement, the H pass and the query path, and the weight and
+bias gradient ``conv3x3_relu_pool_bwd`` in the last two's backward.  Here
+the platform policy is forced to TPU (the kernels then run in interpret
+mode on the CPU) and the launcher's episodic ProtoNets step is compiled
+at a tiny size."""
 import re
 
 import jax
@@ -22,9 +23,10 @@ from repro.launch import train as launcher
 from repro.launch.episodic import EpisodicShape, build_learner
 
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
-# |H|, the complement's chunk and the queries all fill half of the
-# kernel's 128 lanes, so only tangents keep it out of the H pass and the
-# query path
+# the (H, W, C) of an instruction that XLA made of a max-pool's backward
+_POOL_BWD = re.compile(r'= f32\[[\d,]*?(\d+,\d+,\d+)\]\S* .*op_name="[^"]*'
+                       r'select_and_scatter"')
+# |H|, the complement's chunk and the queries all reach the kernel's floor
 SHAPE = EpisodicShape(widths=(8, 16), feature_dim=16, way=2, shot=100,
                       query_per_class=32, image_size=8, lite_h=64,
                       lite_chunk=64)
@@ -51,13 +53,22 @@ def fused_step_hlo(tmp_path_factory):
 
 
 def test_fused_block_runs_only_in_the_complement(fused_step_hlo):
-    names = {n for n in _OP_NAME.findall(fused_step_hlo)
-             if "conv3x3_relu_pool" in n}
-    assert names
-    for n in names:
-        assert "lite.complement" in n, n
-        for other in ("transpose(", "lite.h_pass", "learner.query"):
-            assert other not in n, n
+    """The forward kernel runs in all three parts of the step and never in
+    a backward; the backward kernel runs only there, in the H pass's and
+    the query path's; no max-pool backward is left on block 1's
+    full-resolution (N, 8, 8, 8) tensor, only on block 2's."""
+    names = set(_OP_NAME.findall(fused_step_hlo))
+    bwd = {n for n in names if "conv3x3_relu_pool_bwd" in n}
+    fwd = {n for n in names if "conv3x3_relu_pool" in n} - bwd
+    scopes = ("lite.complement", "lite.h_pass", "learner.query")
+    for n in fwd:
+        assert "transpose(" not in n, n
+    for n in bwd:
+        assert "transpose(" in n and "lite.complement" not in n, n
+    assert {s for s in scopes for n in fwd if s in n} == set(scopes)
+    assert {s for s in scopes for n in bwd if s in n} == set(scopes[1:])
+    pool_bwd = {m.group(1) for m in _POOL_BWD.finditer(fused_step_hlo)}
+    assert pool_bwd == {"4,4,16"}
 
 
 def _bf16_exact(tree):

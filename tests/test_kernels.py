@@ -93,17 +93,80 @@ def test_conv3x3_relu_pool_sweep(key, b, h, w, cin, cout):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("case", [
+    "b3_16x12_cin3", "b130_8x6_cin3", "b40_6x10_cin1", "constant",
+    "negative_bias"])
+def test_conv3x3_relu_pool_bwd_sweep(key, case):
+    """The fused block's weight and bias gradients against ``jax.vjp`` of
+    the XLA composite, on bfloat16-exact operands and cotangent.  Shapes:
+    130 images span two lane blocks, 40 fill part of one.  ``constant``:
+    a constant image under a centre-only kernel, so all four phases of
+    every window tie, and only routing to the first one puts the border
+    windows' other taps on the padding as the composite does.
+    ``negative_bias``: every phase is <= 0, so both gradients are 0."""
+    shapes = {"b3_16x12_cin3": (3, 16, 12, 3, 64),
+              "b130_8x6_cin3": (130, 8, 6, 3, 32),
+              "b40_6x10_cin1": (40, 6, 10, 1, 8)}
+    b, h, w, cin, cout = shapes.get(case, (5, 8, 6, 3, 16))
+    ks = jax.random.split(key, 4)
+    x = _bf16_exact(jax.random.normal(ks[0], (b, h, w, cin)))
+    wt = _bf16_exact(0.3 * jax.random.normal(ks[1], (3, 3, cin, cout)))
+    bias = _bf16_exact(jax.random.normal(ks[2], (cout,)))
+    g = _bf16_exact(jax.random.normal(ks[3], (b, h // 2, w // 2, cout)))
+    if case == "constant":
+        x = jnp.full_like(x, 0.5)
+        wt = jnp.zeros_like(wt).at[1, 1].set(wt[1, 1])
+    if case == "negative_bias":
+        bias = jnp.full_like(bias, -100.0)
+    _, vjp = jax.vjp(lambda w_, b_: conv_backbone._conv_block(x, w_, b_, None),
+                     wt, bias)
+    want_dw, want_db = vjp(g)
+    dw, db = ops.conv3x3_relu_pool_bwd(x, wt, bias, g)
+    assert dw.shape == wt.shape and db.shape == bias.shape
+    if case == "negative_bias":
+        assert not np.any(np.asarray(dw)) and not np.any(np.asarray(db))
+    else:
+        assert np.any(np.asarray(want_dw))
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(want_dw),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(db), np.asarray(want_db),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_image_block_input_gradient_is_the_composites(monkeypatch, key):
+    """An image that carries a tangent takes the XLA composite and its
+    VJP, so every gradient, the input's too, is the composite's; without
+    one, the weights' and bias' gradient is the backward kernel's."""
+    monkeypatch.setattr(dispatch, "pallas_native", lambda: True)
+    x = jax.random.normal(key, (32, 8, 8, 3))
+    wt = 0.3 * jax.random.normal(jax.random.fold_in(key, 1), (3, 3, 3, 8))
+    bias = jax.random.normal(jax.random.fold_in(key, 2), (8,))
+
+    def loss(block, *args):
+        return jnp.sum(jnp.sin(block(*args)))
+
+    composite = lambda x, w, b: conv_backbone._conv_block(x, w, b, None)
+    got = jax.grad(loss, argnums=(1, 2, 3))(conv_backbone._image_block,
+                                            x, wt, bias)
+    want = jax.grad(loss, argnums=(1, 2, 3))(composite, x, wt, bias)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    fused = lambda *args: loss(conv_backbone._image_block, *args)
+    jaxpr = str(jax.make_jaxpr(jax.grad(fused, argnums=(1, 2)))(x, wt, bias))
+    assert "conv3x3_relu_pool_bwd" in jaxpr
+
+
 @pytest.mark.parametrize("case,fused", [
     ("image", True), ("odd_h", False), ("odd_w", False), ("film", False),
-    ("cin_5", False), ("batch_8", False), ("batch_32", False),
-    ("batch_1000", True)])
+    ("cin_5", False), ("batch_8", False), ("batch_16", False),
+    ("batch_32", True), ("batch_1000", True)])
 def test_conv3x3_relu_pool_engages_on_image_blocks_only(monkeypatch, case,
                                                          fused):
     """With the platform policy forced to TPU the backbone fuses a
     FiLM-free block of an image (<= 4 channels) with even H and W and a
-    batch of at least half the kernel's 128 lanes, and only that block;
-    every other block, and a smaller batch (serving's query chunk of 8,
-    its adapt chunk of 32), keeps the composite."""
+    batch of at least a quarter of the kernel's 128 lanes (serving's adapt
+    chunk of 32), and only that block; every other block, and a smaller
+    batch (serving's query chunk of 8), keeps the composite."""
     monkeypatch.setattr(dispatch, "pallas_native", lambda: True)
     cin = 5 if case == "cin_5" else 3
     cfg = conv_backbone.ConvBackboneConfig(in_channels=cin, widths=(8, 8),

@@ -6,7 +6,11 @@ refuses or accepts — block tiling, VMEM limits — and interpret mode cannot
 show.  Widths are the chip smoke's: B=1000 support images, C=10 classes,
 F=256 features, and a K=N=256 int8 head; the fused image block takes the
 Conv-4 cell's complement chunk, 100 images of 224x224x3 into 64 filters,
-alone and vmapped over two tasks, and a whole 1000-image support set.
+alone and vmapped over two tasks, and a whole 1000-image support set; its
+backward takes the query path's 100 images and the H pass's 40.
+
+The ProtoNets Conv-4 LITE step of the benchmark cell compiles whole, with
+the fused block's forward and backward, in under 7.6 GB of temporaries.
 
 The Simple CNAPs + EfficientNet-B0 LITE step of the benchmark cell (224
 px, way 10 x shot 100, 100 queries, |H| 40, chunk 100, F = 1280) compiles
@@ -86,6 +90,16 @@ CASES = {
                  in_axes=(0, None, None)),
         [((2, 100, 224, 224, 3), jnp.float32), ((3, 3, 3, 64), jnp.float32),
          ((64,), jnp.float32)]),
+    # the weight and bias gradient, for the query path's 100 images and
+    # the H pass's 40 (part of one lane block)
+    "conv3x3_relu_pool_bwd": (
+        lambda x, w, b, g: cp.conv3x3_relu_pool_bwd(x, w, b, g),
+        [((100, 224, 224, 3), jnp.float32), ((3, 3, 3, 64), jnp.float32),
+         ((64,), jnp.float32), ((100, 112, 112, 64), jnp.float32)]),
+    "conv3x3_relu_pool_bwd_b40": (
+        lambda x, w, b, g: cp.conv3x3_relu_pool_bwd(x, w, b, g),
+        [((40, 224, 224, 3), jnp.float32), ((3, 3, 3, 64), jnp.float32),
+         ((64,), jnp.float32), ((40, 112, 112, 64), jnp.float32)]),
 }
 
 
@@ -97,27 +111,48 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in _compile_hlo(fn, *shapes)
 
 
-def test_simple_cnaps_efficientnet_step_compiles_for_v5e(one_chip, monkeypatch,
-                                                        tmp_path):
+def _step_on_chip(one_chip, monkeypatch, argv):
+    """The launcher's episodic step for argv, compiled for one described
+    chip with the TPU kernel policy."""
     from repro.kernels import dispatch, ops
     from repro.launch import train as launcher
 
     monkeypatch.setattr(dispatch, "pallas_native", lambda: True)
     monkeypatch.setattr(ops, "_interpret", lambda: False)
-    args = launcher.build_parser().parse_args(
-        ["--episodic", "--learner", "simple_cnaps", "--backbone",
-         "efficientnet_b0", "--tasks-per-step", "1", "--way", "10",
-         "--shot", "100", "--query-per-class", "10", "--image-size", "224",
-         "--lite-h", "40", "--lite-chunk", "100", "--ckpt-dir",
-         str(tmp_path)])
-    job = launcher.build_episodic(args)
+    job = launcher.build_episodic(launcher.build_parser().parse_args(argv))
 
     def on_chip(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
     state = jax.tree.map(on_chip, job.state_abs)
     batch = jax.tree.map(on_chip, jax.eval_shape(job.batch_at, 0))
-    compiled = jax.jit(job.step).lower(state, batch).compile()
+    return jax.jit(job.step).lower(state, batch).compile()
+
+
+def test_protonets_conv4_step_compiles_for_v5e(one_chip, monkeypatch,
+                                               tmp_path):
+    """The ProtoNets Conv-4 cell's step (224 px, way 10 x shot 100, 100
+    queries, |H| 40, chunk 100): no full-resolution block-1 activation or
+    gradient is kept for the query path's backward, so its temporaries
+    stay under 7.6 GB (8.37 GB with the XLA composite's backward)."""
+    compiled = _step_on_chip(one_chip, monkeypatch, [
+        "--episodic", "--learner", "protonets", "--tasks-per-step", "1",
+        "--data-source", "device", "--widths", "64,64,64,64",
+        "--feature-dim", "64", "--way", "10", "--shot", "100",
+        "--query-per-class", "10", "--image-size", "224", "--lite-h", "40",
+        "--lite-chunk", "100", "--ckpt-dir", str(tmp_path)])
+    assert "conv3x3_relu_pool_bwd" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 7.6e9
+
+
+def test_simple_cnaps_efficientnet_step_compiles_for_v5e(one_chip, monkeypatch,
+                                                        tmp_path):
+    compiled = _step_on_chip(one_chip, monkeypatch, [
+        "--episodic", "--learner", "simple_cnaps", "--backbone",
+        "efficientnet_b0", "--tasks-per-step", "1", "--way", "10",
+        "--shot", "100", "--query-per-class", "10", "--image-size", "224",
+        "--lite-h", "40", "--lite-chunk", "100", "--ckpt-dir",
+        str(tmp_path)])
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
