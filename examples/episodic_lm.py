@@ -1,7 +1,7 @@
 """The paper's technique as a first-class LM-framework feature: episodic
 meta-training (ProtoNets + LITE) wrapped around an assigned LM
 architecture — support/query examples are token sequences; FiLM modulates
-the residual stream per layer (DESIGN.md §3).
+the residual stream per layer.
 
     PYTHONPATH=src python examples/episodic_lm.py --arch minitron-4b
 """

@@ -55,6 +55,5 @@ def cell_supported(arch: str, shape_name: str) -> Tuple[bool, str]:
     """Is (arch x shape) a runnable dry-run cell? Returns (ok, reason)."""
     if shape_name == "long_500k" and arch not in LONG_CONTEXT_OK:
         return False, ("full-attention decode at 524288 ctx is O(S) mem / "
-                       "O(S^2) aggregate — sub-quadratic archs only "
-                       "(see DESIGN.md long_500k applicability)")
+                       "O(S^2) aggregate — sub-quadratic archs only")
     return True, ""

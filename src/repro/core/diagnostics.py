@@ -1,5 +1,5 @@
 """Gradient-estimator diagnostics — the paper's §5.3 / Fig. 4 / Tables
-D.7-D.8 harness, as a library (tests and benchmarks/fig4_rmse call in).
+D.7-D.8 harness, as a library (tests/test_meta_learners.py calls in).
 
 For a fixed task and fixed params:
   * exact gradient      g*  = d meta_loss / d params at LiteSpec(exact)

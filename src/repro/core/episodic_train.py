@@ -379,23 +379,3 @@ def jit_task_step(step: Callable, donate: bool = True):
     after the call (on backends implementing donation, reuse raises)."""
     return jax.jit(step, donate_argnums=(0, 1) if donate else ())
 
-
-def run_looped_baseline(learner: MetaLearner, lite: LiteSpec,
-                        params: PyTree, opt_state: Dict, tasks, key,
-                        adamw: AdamWConfig = AdamWConfig(weight_decay=0.0),
-                        lr: float = 1e-3, max_grad_norm: float = 10.0,
-                        donate: bool = False):
-    """Paper Algorithm 1 verbatim: one optimizer step PER task, in a Python
-    loop.  The throughput baseline ``benchmarks/task_throughput.py`` compares
-    the batched engine against; uses the same per-task key convention.
-    ``donate=True`` updates params/opt state in place — the caller's input
-    buffers are consumed by the first step."""
-    step = jit_task_step(make_meta_train_step(learner, lite, adamw=adamw,
-                                              lr=lr,
-                                              max_grad_norm=max_grad_norm),
-                         donate=donate)
-    metrics = None
-    for i, task in enumerate(tasks):
-        params, opt_state, metrics = step(params, opt_state, task,
-                                          task_key(key, i))
-    return params, opt_state, metrics

@@ -491,13 +491,6 @@ def lite_class_stats(features_fn: Callable, params: PyTree, xs: PyTree,
     return stats, counts
 
 
-def lite_value_and_grad(loss_fn: Callable, argnums: int = 0):
-    """Convenience: ``jax.value_and_grad`` for losses already built on
-    ``lite_sum``/``lite_segment_sum`` sites.  Exists so call sites read as a
-    single named concept; the estimator itself lives in the combinators."""
-    return jax.value_and_grad(loss_fn, argnums=argnums)
-
-
 # ---------------------------------------------------------------------------
 # Naive baseline the paper compares against (Fig. 4 / Table D.8): training on
 # a sub-sampled *small task* — forward AND backward see only H examples.

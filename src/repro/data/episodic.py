@@ -1,5 +1,5 @@
 """Synthetic episodic task generators (ORBIT / VTAB+MD stand-ins — the
-real datasets are unavailable offline; DESIGN.md §8 records this).
+real datasets are unavailable offline).
 
 Image tasks: each class is a Gaussian blob in pixel space with a class-
 specific low-frequency pattern — linearly separable enough that accuracy

@@ -10,7 +10,7 @@ Each op selects an implementation per *backend*:
               materializes the per-example ``(B, F, F)`` outer-product
               tensor — the memory bottleneck this subsystem exists to
               kill — so it survives only as the bit-exact legacy oracle
-              the parity tests and benchmarks compare against.
+              the parity tests compare against.
   ``ref``     jnp, reassociated so XLA contracts over the example axis
               without the ``(B, F, F)`` intermediate (the second moment
               becomes ``"bc,bi,bj->cij"`` via a ``(B, C, F)`` hop —

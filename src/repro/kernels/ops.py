@@ -26,7 +26,7 @@ How kernels reach model code — two layers:
 
 * **This module** keeps the raw jit'd wrappers (LM-side kernels and
   direct use: flash_attention, gmm, ssd_chunk, plus the class-statistics
-  kernels for benchmarks/tests).  The XLA/jnp path (ref semantics) is
+  kernels for tests).  The XLA/jnp path (ref semantics) is
   what the SPMD dry-run lowers, so roofline FLOPs stay visible to the
   HLO analyzer either way.
 """

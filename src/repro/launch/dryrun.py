@@ -6,7 +6,7 @@ and record memory / FLOPs / collective traffic for the roofline.
     PYTHONPATH=src python -m repro.launch.dryrun --arch gemma2-2b --shape train_4k
 
 Output: one JSON record per cell appended to --out (default
-benchmarks/results/dryrun.json), keyed "arch/shape/mesh", so interrupted
+$TMPDIR/dryrun.json), keyed "arch/shape/mesh", so interrupted
 sweeps resume where they stopped.
 """
 import os
@@ -18,6 +18,7 @@ os.environ["REPRO_MIXED_DOT"] = "1"   # AOT-only: bf16 dots w/ f32 accum
 import argparse
 import json
 import pathlib
+import tempfile
 import time
 import traceback
 from typing import Callable, Dict
@@ -37,7 +38,7 @@ from repro.sharding import rules
 from repro.sharding.ctx import P
 from repro.train.step import adamw_for, make_init_state, make_train_step
 
-DEFAULT_OUT = pathlib.Path("benchmarks/results/dryrun.json")
+DEFAULT_OUT = pathlib.Path(tempfile.gettempdir()) / "dryrun.json"
 
 
 def _named(tree_specs, mesh):

@@ -3,12 +3,12 @@
 The AST rules catch source-level drift; these contracts catch the
 failures that only exist after SPMD partitioning — a collective that
 silently spans two replica groups, a layout whose wire bytes regressed
-past what the checked-in benchmarks measured, a bucketed engine that
+past its measured budget, a bucketed engine that
 recompiles per request, a lite/chunked learner that materializes a
 per-example outer-product tensor, an int8 serving path that keeps a
 persistent fp32 copy of the frozen slice.  Each contract cell builds the
-same miniature program an existing measured benchmark/test builds
-(so the checked-in CSV numbers are directly comparable), compiles it for
+same miniature program an existing test builds
+(so the measured ``WIRE_BUDGETS`` are directly comparable), compiles it for
 real, and checks the post-SPMD HLO via :mod:`repro.roofline.hlo`.
 
 Cells (4 emulated devices — the CLI re-execs with
@@ -17,12 +17,11 @@ Cells (4 emulated devices — the CLI re-execs with
 ``replica_2x2``    weight-stationary predict on one replica group of
                    ``make_replica_mesh(2, 2)``: no collective group wider
                    than the replica's 2 devices, and per-step wire within
-                   1.5x of ``benchmarks/results/serve_throughput.csv``'s
-                   ``engine_replicas2_none`` row.
+                   1.5x of its ``WIRE_BUDGETS`` entry.
 ``int8_ws``        the int8 weight-stationary layout cell from
                    tests/test_quant_serving: ws wire strictly below the
                    training layout and within 1.5x of
-                   ``serve_layouts.csv``'s measured row; the frozen slice
+                   its ``WIRE_BUDGETS`` entry; the frozen slice
                    stays int8 — s8 entry parameters in the compiled
                    predict, int8 host tree, and measured frozen resident
                    bytes at least 3x below their fp32 equivalent.
@@ -42,42 +41,23 @@ functions build the programs and need jax + 4 devices.
 """
 from __future__ import annotations
 
-import csv
 import math
-import pathlib
 from typing import Dict, Iterable, List, Optional, Sequence
 
-from repro.lint.engine import Finding, repo_root
+from repro.lint.engine import Finding
 
 #: budget slack over the checked-in measured numbers — generous enough to
 #: absorb XLA version noise, tight enough that a layout regression (e.g.
 #: weights gathered per step) blows straight through it
 SLACK = 1.5
 
-RESULTS = ("benchmarks", "results")
-
-
 # ---------------------------------------------------------------- budgets
 
-def _csv_rows(name: str) -> List[Dict[str, str]]:
-    path = repo_root().joinpath(*RESULTS, name)
-    with open(path, newline="") as f:
-        return list(csv.DictReader(f))
-
-
-def serve_layout_budgets(regime: str = "serve_small") -> Dict[str, float]:
-    """layout -> measured wire_bytes from serve_layouts.csv."""
-    return {r["layout"]: float(r["wire_bytes"])
-            for r in _csv_rows("serve_layouts.csv") if r["regime"] == regime}
-
-
-def replica_wire_budget(mode: str = "engine_replicas2_none") -> float:
-    """One replica's measured per-step predict wire from
-    serve_throughput.csv."""
-    for r in _csv_rows("serve_throughput.csv"):
-        if r["mode"] == mode:
-            return float(r["wire_per_replica_bytes"])
-    raise KeyError(f"no row {mode!r} in serve_throughput.csv")
+#: per-step compiled-HLO wire bytes as the removed serve_layouts.csv
+#: (serve_small) and serve_throughput.csv (one replica of 2) recorded them
+WIRE_BUDGETS = {"serve_small/training": 117888.0,
+                "serve_small/weight_stationary": 15552.0,
+                "replicas2_none/per_replica": 2560.0}
 
 
 # ---------------------------------------------------------- pure checks
@@ -103,7 +83,7 @@ def check_wire_budget(wire_bytes: float, budget: float,
     if wire_bytes > slack * budget:
         return [f"{label}: per-step wire {wire_bytes:.0f}B exceeds "
                 f"{slack}x the checked-in budget {budget:.0f}B — the "
-                f"layout regressed (re-measure and re-commit the CSV if "
+                f"layout regressed (re-measure and update WIRE_BUDGETS if "
                 f"intentional)"]
     return []
 
@@ -248,7 +228,7 @@ def _compile_predict(learner, sw, states, query_x, mesh, layout: str):
 
 def cell_replica_2x2() -> List[str]:
     """One group of make_replica_mesh(2, 2): intra-group-only collectives
-    + wire budget (mirrors benchmarks/serve_throughput.py's replica rows)."""
+    + wire budget (tests/test_replica.py checks group-scoped wire too)."""
     _require_devices(4)
     import jax
     import jax.numpy as jnp
@@ -288,7 +268,8 @@ def cell_replica_2x2() -> List[str]:
                             meshes[0], "weight_stationary")
     rep = collectives_report(text)
     msgs = check_inter_group(rep["per_kind"], group_size=2)
-    msgs += check_wire_budget(rep["total_wire_bytes"], replica_wire_budget(),
+    msgs += check_wire_budget(rep["total_wire_bytes"],
+                              WIRE_BUDGETS["replicas2_none/per_replica"],
                               "replica_2x2 weight_stationary predict")
     return msgs
 
@@ -338,8 +319,7 @@ def cell_int8_ws() -> List[str]:
     ws = collectives_report(ws_text)["total_wire_bytes"]
     tr = collectives_report(tr_text)["total_wire_bytes"]
 
-    budgets = serve_layout_budgets("serve_small")
-    msgs = check_wire_budget(ws, budgets["weight_stationary"],
+    msgs = check_wire_budget(ws, WIRE_BUDGETS["serve_small/weight_stationary"],
                              "int8_ws weight_stationary predict")
     if not ws < tr:
         msgs.append(

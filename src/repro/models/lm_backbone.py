@@ -1,4 +1,4 @@
-"""Expose LM-family architectures as episodic BackboneDefs (DESIGN.md §3):
+"""Expose LM-family architectures as episodic BackboneDefs:
 the paper's scheme wraps ANY feature extractor — here the support/query
 "examples" are token sequences and features are mean-pooled final hidden
 states, with per-layer FiLM on the residual stream as the adaptation site.

@@ -98,7 +98,7 @@ def block(cfg: ModelConfig, lp: Params, x: jnp.ndarray, window: jnp.ndarray,
           film: Optional[Dict] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (B, S, D) -> (x', aux_loss).  ``film`` (episodic adaptation):
     per-layer {gamma, beta} of width d_model applied to the residual
-    stream after the block — the LM-family FiLM site (DESIGN.md §3)."""
+    stream after the block — the LM-family FiLM site."""
     a = cfg.attention
     h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     if a.kind == "mla":

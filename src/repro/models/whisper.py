@@ -5,7 +5,7 @@ consumes precomputed frame embeddings (B, S_enc, d_model) provided by
 ``input_specs`` / the data pipeline.  Encoder: bidirectional self-attention;
 decoder: causal self-attention + cross-attention to the encoder output.
 
-Deviation from the original (recorded in DESIGN.md): rotary positions
+Deviation from the original: rotary positions
 instead of learned/sinusoidal tables, so sequence length is unconstrained
 for the assigned 32k decode shapes.
 """

@@ -8,8 +8,7 @@ Compression is a gradient transform around the reduction point:
 
 ``compressed_psum`` is the shard_map reduction: the WIRE carries the int8
 payload plus the per-128-block f32 scales (an ``all_gather`` of
-``{q, scale}`` over the axis — ~1.03 bytes/element vs 4 for an f32 psum,
-verified by ``collectives_report`` in benchmarks/dp_scaling.py); each
+``{q, scale}`` over the axis — ~1.03 bytes/element vs 4 for f32 psum); each
 shard decodes and sums locally, which equals the psum of the per-shard
 decoded values.  Error feedback (the carried residual) keeps the long-run
 quantization bias at zero; numerics are covered by tests/test_optim.py

@@ -1,136 +1,13 @@
-"""Three-term roofline from dry-run records (EXPERIMENTS.md §Roofline).
-
-Per (arch x shape x mesh) cell:
-    T_compute = dot_FLOPs_per_chip / peak_FLOPs
-    T_memory  = bytes_per_chip / HBM_bw
-    T_coll    = ring wire bytes_per_chip / ICI_link_bw
-    bottleneck = argmax of the three
-    MODEL_FLOPS = 6 N_active D   (train; 2 N_active D for inference pass)
-    useful ratio = MODEL_FLOPS_per_chip / dot_FLOPs_per_chip
-    roofline fraction = T_ideal / T_bound,  T_ideal = MODEL_FLOPS/(chips*peak)
-
-The fraction answers "how close would a perfectly-overlapped execution of
-this compiled program run to the hardware bound set by its own dominant
-term" — the score §Perf iterates on.
-"""
+"""Serving-layout scorer: compile a serving step under each candidate
+weight layout and score the compiled HLO with a three-term roofline
+(compute, HBM bytes, collective wire; the largest term bounds the step)."""
 from __future__ import annotations
 
-import json
-import pathlib
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 
-from repro.configs.base import SHAPES_BY_NAME, ModelConfig
-from repro.configs.registry import get_config
-from repro.launch.specs import abstract_params_for
 from repro.roofline.constants import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
-
-
-def param_counts(cfg: ModelConfig) -> Dict[str, float]:
-    """(total, active) parameter counts from the abstract param tree.
-    Expert banks (3D+ leaves under 'ffn' with leading E) count at
-    (top_k + n_shared)/E toward active."""
-    params = abstract_params_for(cfg)
-    total = 0.0
-    active = 0.0
-    embed = 0.0
-
-    def visit(path, leaf):
-        nonlocal total, active, embed
-        p = "/".join(str(getattr(x, "key", getattr(x, "idx", x))) for x in path)
-        n = 1.0
-        for d in leaf.shape:
-            n *= d
-        total += n
-        name = p.split("/")[-1]
-        if name in ("embed", "lm_head"):
-            embed += n
-            return                      # embeddings excluded from 6ND flops
-        if cfg.moe is not None and "ffn" in p and len(leaf.shape) >= 3 \
-                and leaf.shape[-3] == cfg.moe.n_experts:
-            active += n * cfg.moe.top_k / cfg.moe.n_experts
-        else:
-            active += n
-
-    jax.tree_util.tree_map_with_path(visit, params)
-    return dict(total=total, active=active, embed=embed)
-
-
-def model_flops(cfg: ModelConfig, shape_name: str) -> float:
-    """Global MODEL_FLOPS for one step of this cell."""
-    s = SHAPES_BY_NAME[shape_name]
-    counts = param_counts(cfg)
-    n_active = counts["active"]
-    if s.kind == "train":
-        tokens = s.global_batch * s.seq_len
-        return 6.0 * n_active * tokens
-    if s.kind == "prefill":
-        tokens = s.global_batch * s.seq_len
-        return 2.0 * n_active * tokens
-    # decode: one token per sequence
-    return 2.0 * n_active * s.global_batch
-
-
-def analyze_cell(rec: Dict) -> Optional[Dict]:
-    if rec.get("status") != "ok":
-        return None
-    cfg = get_config(rec["arch"])
-    chips = rec["chips"]
-    t_compute = rec["flops_per_device"] / PEAK_FLOPS_BF16
-    t_memory = rec["bytes_per_device"] / HBM_BW
-    wire = sum(k["wire_bytes"] for k in rec["collectives"].values())
-    t_coll = wire / ICI_BW_PER_LINK
-    terms = dict(compute=t_compute, memory=t_memory, collective=t_coll)
-    bottleneck = max(terms, key=terms.get)
-    mf = model_flops(cfg, rec["shape"])
-    t_ideal = mf / (chips * PEAK_FLOPS_BF16)
-    t_bound = max(terms.values())
-    useful = mf / chips / max(rec["flops_per_device"], 1.0)
-    return dict(
-        arch=rec["arch"], shape=rec["shape"], mesh=rec["mesh"], chips=chips,
-        t_compute=t_compute, t_memory=t_memory, t_collective=t_coll,
-        bottleneck=bottleneck,
-        model_flops=mf, useful_ratio=useful,
-        roofline_fraction=t_ideal / max(t_bound, 1e-30),
-        state_bytes_per_device=rec.get("state_bytes_per_device", 0),
-        hbm_headroom_gib=16.0 - rec.get("state_bytes_per_device", 0) / 2**30,
-    )
-
-
-def load_table(path: str | pathlib.Path, mesh: str = "single"):
-    recs = json.loads(pathlib.Path(path).read_text())
-    rows = []
-    for key, rec in sorted(recs.items()):
-        if rec.get("mesh") != mesh:
-            continue
-        if rec.get("status") == "skipped":
-            rows.append(dict(arch=rec["arch"], shape=rec["shape"],
-                             mesh=mesh, skipped=rec["reason"][:60]))
-            continue
-        out = analyze_cell(rec)
-        if out:
-            rows.append(out)
-    return rows
-
-
-def format_markdown(rows) -> str:
-    hdr = ("| arch | shape | T_comp (ms) | T_mem (ms) | T_coll (ms) | "
-           "bottleneck | useful | roofline frac | state GiB/chip |")
-    sep = "|" + "---|" * 9
-    lines = [hdr, sep]
-    for r in rows:
-        if "skipped" in r:
-            lines.append(f"| {r['arch']} | {r['shape']} | — | — | — | "
-                         f"skipped | — | — | — |")
-            continue
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {1e3*r['t_compute']:.2f} | "
-            f"{1e3*r['t_memory']:.2f} | {1e3*r['t_collective']:.2f} | "
-            f"{r['bottleneck']} | {r['useful_ratio']:.2f} | "
-            f"{r['roofline_fraction']:.3f} | "
-            f"{r['state_bytes_per_device']/2**30:.2f} |")
-    return "\n".join(lines)
 
 
 # ---------------------------------------------------------------------------

@@ -337,12 +337,12 @@ def xla_cost_analysis(compiled) -> Dict:
 
     Across jax versions ``cost_analysis()`` has returned a plain dict, a
     per-device LIST of dicts, or ``None`` — the raw call un-crashed three
-    separate benchmarks before the callers learned to normalize it, each
+    separate callers before they learned to normalize it, each
     with its own copy of the fix.  This is the one shared shim: always a
     plain dict (device 0's entry on list-returning versions, ``{}`` when
     the analysis is absent).  Remember its numbers are loop-NAIVE (see
     module doc) — use :func:`analyze` for roofline inputs; this exists for
-    cross-checks and the MACs-style accounting the benchmarks print.  The
+    cross-checks and MACs-style accounting.  The
     ``raw-cost-analysis`` lint rule rejects bare call sites outside this
     module."""
     cost = compiled.cost_analysis()
@@ -366,7 +366,7 @@ def collectives_report(compiled_or_text) -> Dict:
     ``total_wire_bytes / link_bandwidth`` is the step's collective
     time bound.  This is the same walk the dry-run records and the
     shard_map-vs-GSPMD wire-bytes regression guard assert on; the
-    two-level DP engine's tests and ``benchmarks/dp_scaling.py`` use it to
+    two-level DP engine's tests (tests/test_multihost.py) use it to
     account the cross-host gradient-reduction traffic per train step."""
     text = compiled_or_text if isinstance(compiled_or_text, str) \
         else compiled_or_text.as_text()

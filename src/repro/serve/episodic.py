@@ -531,8 +531,8 @@ class EpisodicServeEngine:
         (e.g. ``weight_stationary``: contracting dims sharded so the
         per-step wire carries small activations instead of gathered
         weights); resolve ``'auto'`` to a concrete name with
-        ``choose_serving_layout`` BEFORE construction (the launcher and
-        benchmarks do) — the engine applies a layout, it does not score
+        ``choose_serving_layout`` BEFORE construction (the serving
+        launcher does) — the engine applies a layout, it does not score
         one.  In a multi-replica deployment ``mesh`` is the replica's OWN
         disjoint device group (``make_replica_mesh``): weights are
         stationary within the group and no predict-step collective ever

@@ -174,7 +174,7 @@ def place_serving_weights(sw: ServingWeights, mesh, layout) -> ServingWeights:
 def param_bytes(sw: ServingWeights) -> Dict[str, int]:
     """Measured resident parameter bytes (host-side accounting over the
     ACTUAL stored arrays — not a model).  Returns totals plus the frozen
-    slice alone (the ≥3x reduction guard in tests/benchmarks), and the
+    slice alone (the ≥3x reduction guard in the tests), and the
     fp32-equivalent bytes the same leaves would occupy unquantized."""
     tot = tot_fp32 = froz = froz_fp32 = 0
     flat, _ = jax.tree_util.tree_flatten_with_path(

@@ -26,8 +26,7 @@ Two-level DP mesh contract (the task-batched meta-training engine,
 * At ``dcn`` size 1 the pmean path skips the dcn reduction, so the
   engine is bit-identical to the 1-D ``make_dp_mesh`` path (tested in
   tests/test_multihost.py).  Per-step collective wire bytes are
-  accounted by ``repro.roofline.hlo.collectives_report`` and tracked in
-  ``benchmarks/dp_scaling.py``.
+  accounted by ``repro.roofline.hlo.collectives_report`` (its wire test there).
 
 Replica-serving mesh contract (the multi-replica episodic engine,
 ``repro.serve.replica.ReplicatedServeEngine``):

@@ -1,6 +1,6 @@
 """Partition rules: map parameter/cache/batch pytrees to PartitionSpecs.
 
-Strategy (per DESIGN.md §5):
+Strategy:
   * 'model' axis = tensor/expert parallel (attention heads, FFN hidden,
     MoE expert dim, vocab).
   * 'data' (+ 'pod' when present) = data parallel for activations AND the

@@ -8,7 +8,8 @@ topology-free (plain host arrays), so elasticity reduces to:
     new_mesh  = choose_mesh(len(live_devices))
     new_state = shard(state_np, new_specs(new_mesh))
 
-The round-trip 8 -> 4 -> 8 devices is covered by tests/test_elastic.py.
+The round-trip 8 -> 4 -> 8 devices is covered by
+tests/test_distributed.py::test_elastic_reshard_roundtrip.
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """Fault-tolerant training loop.
 
-Design for 1000+ nodes (DESIGN.md §5):
+Design for 1000+ nodes:
   * the step is a pure jitted function of (state, batch); the data
     pipeline is a pure function of (config, step)  =>  restart from any
     committed checkpoint is bit-exact (tested by killing mid-run);

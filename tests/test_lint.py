@@ -14,8 +14,7 @@ import pytest
 from repro.lint import engine, rules
 from repro.lint.contracts import (check_compile_flat, check_inter_group,
                                   check_wire_budget, entry_param_dtypes,
-                                  find_outer_tensors, replica_wire_budget,
-                                  serve_layout_budgets)
+                                  find_outer_tensors, WIRE_BUDGETS)
 
 pytestmark = pytest.mark.lint
 
@@ -337,10 +336,9 @@ def test_entry_param_dtypes_toy_hlo():
 
 
 def test_budget_readers_match_checked_in_csvs():
-    budgets = serve_layout_budgets("serve_small")
-    assert budgets["weight_stationary"] == 15552.0
-    assert budgets["training"] == 117888.0
-    assert replica_wire_budget() == 2560.0
+    assert WIRE_BUDGETS["serve_small/weight_stationary"] == 15552.0
+    assert WIRE_BUDGETS["serve_small/training"] == 117888.0
+    assert WIRE_BUDGETS["replicas2_none/per_replica"] == 2560.0
 
 
 # --------------------------------------- contract cells (4 fake devices)

@@ -404,3 +404,14 @@ def test_weight_stationary_moves_less_wire_than_training():
     assert out.returncode == 0, out.stderr[-3000:]
     tr, ws = [int(v) for v in out.stdout.split("WIRE", 1)[1].split()[:2]]
     assert ws < tr
+
+
+def test_layout_scorer_imports_no_model_stack():
+    code = ("import sys, repro.roofline.analysis; print(sorted(m for m in "
+            "sys.modules if m.startswith(('repro.configs', 'repro.launch', "
+            "'repro.models'))))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, env=dict(
+                             os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=SRC))
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip() == "[]", out.stdout
