@@ -16,8 +16,11 @@ MBConv block:
 
 Batch norm is frozen, as LITE freezes the backbone: a per-channel affine
 from stored gamma, beta, mean and var (eps 1e-3).  No drop-connect: the
-backbone runs in eval mode.  1x1 convolutions are matrix products over
-the channel axis.
+backbone runs in eval mode.  1x1 convolutions are convolutions, as the
+stem and the depthwise convs are, so every activation stays in XLA's
+convolution layout; their weights are stored (cin, cout).  The expand
+(1x1, BN, swish) is recomputed in the backward from the block input,
+which is 6x narrower, rather than saved.
 
 Weights are random (there are none pretrained here) and the statistics
 identity, at which the activations shrink about threefold per block: a
@@ -143,11 +146,21 @@ def _conv(h: jnp.ndarray, w: jnp.ndarray, stride: int,
         feature_group_count=groups)
 
 
+def _conv1x1(h: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """A 1x1 convolution of a (cin, cout) weight."""
+    return _conv(h, w[None, None], 1)
+
+
+def _conv1x1_bn_swish(h: jnp.ndarray, p: Dict, eps: float) -> jnp.ndarray:
+    return jax.nn.swish(_bn(_conv1x1(h, p["w"]), p["bn"], eps))
+
+
 def _mbconv(h: jnp.ndarray, p: Dict, spec: MBConvSpec,
             film: Optional[Dict], eps: float) -> jnp.ndarray:
     x = h
     if "expand" in p:
-        h = jax.nn.swish(_bn(h @ p["expand"]["w"], p["expand"]["bn"], eps))
+        h = jax.checkpoint(_conv1x1_bn_swish, static_argnums=2)(
+            h, p["expand"], eps)
     h = _bn(_conv(h, p["dw"]["w"], spec.stride, groups=spec.mid),
             p["dw"]["bn"], eps)
     if film is not None:
@@ -156,7 +169,7 @@ def _mbconv(h: jnp.ndarray, p: Dict, spec: MBConvSpec,
     se = p["se"]
     s = jax.nn.swish(jnp.mean(h, axis=(1, 2)) @ se["w1"] + se["b1"])
     h = h * jax.nn.sigmoid(s @ se["w2"] + se["b2"])[:, None, None, :]
-    h = _bn(h @ p["project"]["w"], p["project"]["bn"], eps)
+    h = _bn(_conv1x1(h, p["project"]["w"]), p["project"]["bn"], eps)
     return h + x if spec.residual else h
 
 
@@ -170,7 +183,7 @@ def efficientnet_features(params: Dict, x: jnp.ndarray,
     for i, (spec, blk) in enumerate(zip(block_specs(cfg),
                                         params["blocks"])):
         h = _mbconv(h, blk, spec, None if film is None else film[i], eps)
-    h = jax.nn.swish(_bn(h @ params["head"]["w"], params["head"]["bn"], eps))
+    h = _conv1x1_bn_swish(h, params["head"], eps)
     return jnp.mean(h, axis=(1, 2))
 
 

@@ -104,8 +104,45 @@ def test_published_widths_and_strides():
                 for _ in range(st[4])]
     assert len(sides) == 16
     assert sides == [TABLE1_SIDES[s] for s in stage_of]
-    last = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    last = [e for e in jaxpr.jaxpr.eqns
+            if e.primitive.name == "conv_general_dilated"
+            and e.params["feature_group_count"] == 1]
     assert last[-1].outvars[0].aval.shape == (1, 7, 7, 1280)
+
+
+def _all_eqns(jaxpr):
+    """The equations of jaxpr and of every jaxpr nested in them."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _all_eqns(inner)
+
+
+def test_one_by_ones_are_convolutions_and_the_expand_is_recomputed():
+    """Every 1x1 (expand, project, head) is a convolution, so no
+    activation leaves the convolution layout for a matrix product; only
+    squeeze-and-excitation's pooled (B, C) products are dot_generals.  Each
+    of the 15 expanding blocks' expand sits in one checkpoint, so the
+    backward recomputes it."""
+    bb = en.make_efficientnet_backbone()
+    params = jax.eval_shape(bb.init, jax.random.key(0))
+    film = [dict(gamma=jax.ShapeDtypeStruct((c,), jnp.float32),
+                 beta=jax.ShapeDtypeStruct((c,), jnp.float32))
+            for c in bb.film_sites]
+    x = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32)
+    jaxpr = jax.make_jaxpr(bb.features)(params, x, film).jaxpr
+    dots = [e for e in _all_eqns(jaxpr) if e.primitive.name == "dot_general"]
+    assert len(dots) == 32
+    assert all(v.aval.ndim == 2 for e in dots for v in e.invars)
+    remat = jax.make_jaxpr(jax.checkpoint(jnp.sin))(1.0).eqns[0].primitive
+    expands = [e for e in jaxpr.eqns if e.primitive is remat]
+    assert len(expands) == sum(s.expand != 1 for s in en.block_specs(
+        en.EfficientNetConfig())) == 15
+    assert all(any(q.primitive.name == "conv_general_dilated"
+                   for q in e.params["jaxpr"].eqns) for e in expands)
 
 
 def _trained(tree):
